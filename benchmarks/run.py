@@ -32,6 +32,8 @@ def main() -> None:
     args = ap.parse_args()
     only = [m.strip() for m in args.only.split(",") if m.strip()]
     skip = [m.strip() for m in args.skip.split(",") if m.strip()]
+    from repro.compile_cache import setup_compile_cache
+    setup_compile_cache()
 
     print("name,us_per_call,derived")
     failures = 0

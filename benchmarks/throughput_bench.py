@@ -327,6 +327,8 @@ def run_smoke() -> list[tuple[str, float, str]]:
 
 def main(argv=None) -> int:
     argv = sys.argv[1:] if argv is None else argv
+    from repro.compile_cache import setup_compile_cache
+    setup_compile_cache()
     rows = run_smoke() if "--smoke" in argv else run()
     print("name,us_per_call,derived")
     for name, us, derived in rows:

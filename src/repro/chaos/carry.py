@@ -19,7 +19,7 @@ from __future__ import annotations
 import dataclasses
 
 import jax
-import jax.numpy as jnp
+import numpy as np
 
 from repro.core.types import Array
 
@@ -36,7 +36,8 @@ class ChaosCarry:
 def make_chaos_carry(n_sites: int, k: int, qnames) -> ChaosCarry:
     # distinct buffers per query (donated-carry runs refuse aliasing);
     # NaN = nothing has ever arrived, matching the event cloud's empty serve
+    # (host leaves: the runtime places the carry, like init_state's)
     return ChaosCarry(
-        live=jnp.ones((n_sites,), bool),
-        served={q: jnp.full((n_sites, k), jnp.nan, jnp.float32)
+        live=np.ones((n_sites,), bool),
+        served={q: np.full((n_sites, k), np.nan, np.float32)
                 for q in qnames})

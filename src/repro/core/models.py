@@ -17,8 +17,13 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.types import Array, CompactModel
+from repro.kernels.dispatch import resolve_use_kernel
 
 _RIDGE = 1e-6
+# full-f32 contractions: a TPU runs f32 matmuls as one bf16 pass by default,
+# and the explained variance is a difference of two large sums
+_HIGHEST = jax.lax.Precision.HIGHEST
+_mm = functools.partial(jnp.matmul, precision=_HIGHEST)
 
 
 def _features(u: Array, degree: int) -> Array:
@@ -38,10 +43,10 @@ def _fit_one(y: Array, x_pred: Array, pair_mask: Array, degree: int):
     scale = jnp.sqrt(jnp.maximum(var_p, 1e-12))
     u = (x_pred - loc) / scale
     f = _features(u, degree) * w[:, None]
-    xtx = f.T @ f + _RIDGE * jnp.eye(4, dtype=f.dtype)
-    xty = f.T @ (y * w)
+    xtx = _mm(f.T, f) + _RIDGE * jnp.eye(4, dtype=f.dtype)
+    xty = _mm(f.T, y * w)
     coeffs = jnp.linalg.solve(xtx, xty)
-    fitted = f @ coeffs
+    fitted = _mm(f, coeffs)
     mean_fit = jnp.sum(fitted * w) / n
     # Var[E[X|Xp]] — unbiased over co-valid samples (the V_i of eqs. 3/7/11)
     ev = jnp.sum(((fitted - mean_fit) ** 2) * w) / jnp.maximum(n - 1.0, 1.0)
@@ -55,12 +60,14 @@ def fit_models(values: Array, counts: Array, predictor: Array,
                interpret: bool = False) -> CompactModel:
     """Fit E[X_i | X_{p_i}] for every stream i in one vmapped pass.
 
-    ``use_kernel=True`` routes the normal-equation accumulations through
+    With the kernel on (``use_kernel=True``, or None on a TPU — resolved by
+    :func:`repro.kernels.dispatch.resolve_use_kernel`, like the
+    stream-statistics pass) the normal-equation accumulations run through
     the fused Pallas ``vandermonde_moments`` kernel (one pass over the
-    window instead of materializing the (N, 4) feature matrix); any other
-    value keeps the reference least-squares path bit-for-bit.  Both solve
-    the same ridge system, so they agree to f32 association noise (pinned
-    in tests/test_models_fit.py).
+    window instead of materializing the (N, 4) feature matrix); otherwise
+    the reference least-squares path runs.  Both solve the same ridge
+    system, so they agree to f32 association noise (pinned in
+    tests/test_models_fit.py).
     """
     n_max = values.shape[-1]
     idx = jnp.arange(n_max)[None, :]
@@ -68,7 +75,7 @@ def fit_models(values: Array, counts: Array, predictor: Array,
     xp = values[predictor]          # (k, N)
     mp = mask[predictor]            # predictor validity
     pair = mask * mp
-    if use_kernel is True:
+    if resolve_use_kernel(use_kernel, interpret):
         coeffs, loc, scale, ev = _fit_fused(values, xp, pair, degree,
                                             interpret)
     else:
@@ -105,8 +112,10 @@ def _fit_fused(values: Array, xp: Array, pair: Array, degree: int,
     keep = (idx4 <= degree).astype(pu.dtype)
     c = coeffs * keep[None, :]
     gram = pu[:, idx4[:, None] + idx4[None, :]]      # (k, 4, 4) Hankel
-    s = jnp.einsum("km,km->k", c, pu[:, :4])         # sum of fitted*w
-    ss = jnp.einsum("ki,kij,kj->k", c, gram, c)      # sum of fitted^2*w
+    s = jnp.einsum("km,km->k", c, pu[:, :4],
+                   precision=_HIGHEST)                       # sum fitted*w
+    ss = jnp.einsum("ki,kij,kj->k", c, gram, c,
+                    precision=_HIGHEST)                      # sum fitted^2*w
     ev = jnp.maximum(ss - s * s / n, 0.0) / jnp.maximum(n - 1.0, 1.0)
     return coeffs, loc, scale, ev
 
@@ -160,10 +169,10 @@ def _fit_one_multi(y: Array, xp: Array, xq: Array, pair_mask: Array):
     u, loc_u, sc_u = std(xp)
     v, loc_v, sc_v = std(xq)
     f = jnp.stack([jnp.ones_like(u), u, v, u * v], axis=-1) * w_[:, None]
-    xtx = f.T @ f + _RIDGE * jnp.eye(4, dtype=f.dtype)
-    xty = f.T @ (y * w_)
+    xtx = _mm(f.T, f) + _RIDGE * jnp.eye(4, dtype=f.dtype)
+    xty = _mm(f.T, y * w_)
     coeffs = jnp.linalg.solve(xtx, xty)
-    fitted = f @ coeffs
+    fitted = _mm(f, coeffs)
     mean_fit = jnp.sum(fitted * w_) / n
     ev = jnp.sum(((fitted - mean_fit) ** 2) * w_) / jnp.maximum(n - 1.0, 1.0)
     return coeffs, jnp.stack([loc_u, loc_v]), jnp.stack([sc_u, sc_v]), ev

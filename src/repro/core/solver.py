@@ -271,7 +271,7 @@ def solve_ipm(p: ProblemData) -> tuple[np.ndarray, float, np.ndarray, bool]:
     q = p.weights**2 * p.sigma2_obj
     # The barrier Hessian conditioning (1/slack^2 terms) needs f64; the solve
     # runs edge/host-side so this never touches the MXU fast path.
-    with jax.experimental.enable_x64(True):
+    with jax.enable_x64(True):
         n, fval, viol, _gap = _ipm(jnp.asarray(q, jnp.float64),
                                    jnp.asarray(A, jnp.float64),
                                    jnp.asarray(b, jnp.float64),

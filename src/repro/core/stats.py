@@ -8,6 +8,7 @@ to these functions as the oracle.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -16,6 +17,9 @@ from repro.api.registry import DEPENDENCE
 from repro.core.types import Array, StreamStats, WindowBatch
 
 _EPS = 1e-12
+# full-f32 contractions: a TPU runs f32 matmuls as one bf16 pass by default,
+# too coarse for moments that are differences of large products
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _mask(values: Array, counts: Array) -> Array:
@@ -60,11 +64,12 @@ def masked_cov(values: Array, counts: Array) -> Array:
     natural estimator.  Unbiased (n_pair - 1) normalization.
     """
     m = _mask(values, counts)
-    n_pair = m @ m.T  # (k,k) number of co-valid positions
+    mm = functools.partial(jnp.matmul, precision=_HIGHEST)
+    n_pair = mm(m, m.T)  # (k,k) number of co-valid positions
     n_pair_c = jnp.maximum(n_pair, 1.0)
-    s1 = (values * m) @ m.T  # sum_i over co-valid with j
+    s1 = mm(values * m, m.T)  # sum_i over co-valid with j
     # pairwise means differ per (i,j); compute E[xy] - E[x]E[y] over co-valid set
-    sxy = (values * m) @ (values * m).T
+    sxy = mm(values * m, (values * m).T)
     mean_i = s1 / n_pair_c
     mean_j = mean_i.T
     cov = sxy / n_pair_c - mean_i * mean_j
@@ -181,23 +186,28 @@ def corr_from_sums(mom: Array, xxt: Array, counts: Array) -> Array:
     return _cov_corr_from_sums(mom, xxt, counts)[1]
 
 
-def stats_from_sums(mom: Array, xxt: Array, counts: Array) -> StreamStats:
+def stats_from_sums(mom: Array, xxt: Array, counts: Array,
+                    shift: Optional[Array] = None) -> StreamStats:
     """Raw sums of zero-masked values -> :class:`StreamStats`, batched.
 
     mom: (..., k, 4) holding S1..S4; xxt: (..., k, k); counts: (..., k).
+    ``shift`` (..., k): the sums are of ``x - shift`` (callers centre the
+    window so the one-pass formulas keep their f32 digits); every moment
+    but the mean is shift-invariant, and the mean adds it back.
     The returned ``corr`` is Pearson; Spearman callers substitute via
     :func:`corr_from_sums` on rank sums (dataclasses.replace).
     """
     c = counts.astype(mom.dtype)
     n = jnp.maximum(c, 1.0)
     s1, s2, s3, s4 = (mom[..., i] for i in range(4))
-    mean = s1 / n
-    m2 = s2 / n - mean**2
+    mu = s1 / n
+    m2 = s2 / n - mu**2
     var = m2 * n / jnp.maximum(n - 1.0, 1.0)
-    m4 = (s4 - 4.0 * mean * s3 + 6.0 * mean**2 * s2 - 3.0 * mean**4 * n) / n
+    m4 = (s4 - 4.0 * mu * s3 + 6.0 * mu**2 * s2 - 3.0 * mu**4 * n) / n
     m4 = jnp.maximum(m4, 0.0)
     vov = var_of_var_estimator(var, m4, counts)
     cov, corr = _cov_corr_from_sums(mom, xxt, counts)
+    mean = mu if shift is None else mu + shift
     return StreamStats(count=counts, mean=mean, var=var, m4=m4,
                        var_of_var=vov, cov=cov, corr=corr)
 

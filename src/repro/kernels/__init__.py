@@ -9,7 +9,9 @@ flash_attention — online-softmax attention forward (causal/sliding-window,
                   dominates the dense-arch roofline (EXPERIMENTS.md §Perf A4).
 
 Each kernel ships kernel.py (pl.pallas_call + BlockSpec), ops.py (jit'd
-wrapper; picks interpret mode off-TPU), ref.py (pure-jnp oracle).
+wrapper: padding, and kernel-or-reference through dispatch.py — the
+compiled kernel on TPU, the reference or interpret mode elsewhere), ref.py
+(pure-jnp oracle).
 """
 from repro.kernels.stream_stats.ops import (fleet_window_moments_xxt,
                                             window_moments_xxt)

@@ -68,4 +68,5 @@ def polyfit_pallas(y: jax.Array, u: jax.Array, tk: int = DEFAULT_TK,
             jax.ShapeDtypeStruct((k, 4), jnp.float32),
         ],
         interpret=interpret,
+        name="polyfit",
     )(y, u)

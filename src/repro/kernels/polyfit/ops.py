@@ -7,6 +7,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.dispatch import resolve_use_kernel
 from repro.kernels.polyfit.kernel import (DEFAULT_TK, DEFAULT_TN,
                                           polyfit_pallas)
 from repro.kernels.polyfit.ref import polyfit_ref
@@ -27,7 +28,7 @@ def vandermonde_moments(y: jax.Array, u: jax.Array, use_kernel: bool = True,
     the masked sums already and only the m=0 row needs the true count.
     """
     k, n = y.shape
-    if not use_kernel:
+    if not resolve_use_kernel(use_kernel, interpret):
         pu, py = polyfit_ref(y, u)
     else:
         tk = min(DEFAULT_TK, max(1, k))

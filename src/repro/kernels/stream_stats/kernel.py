@@ -26,6 +26,9 @@ from jax.experimental import pallas as pl
 
 DEFAULT_TK = 8
 DEFAULT_TN = 512
+# full-f32 MXU passes: the statistics subtract XX^T/n from mean products, so
+# a single bf16 pass would leave them with ~3 significant digits
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _kernel(xi_ref, xj_ref, xxt_ref, mom_ref):
@@ -40,7 +43,7 @@ def _kernel(xi_ref, xj_ref, xxt_ref, mom_ref):
         xxt_ref[...] = jnp.zeros_like(xxt_ref)
 
     xxt_ref[...] += jax.lax.dot_general(
-        xi, xj, (((1,), (1,)), ((), ())),
+        xi, xj, (((1,), (1,)), ((), ())), precision=_HIGHEST,
         preferred_element_type=jnp.float32)       # MXU tile
 
     @pl.when(j == 0)
@@ -67,7 +70,7 @@ def _fleet_kernel(x_ref, xxt_ref, mom_ref):
         mom_ref[...] = jnp.zeros_like(mom_ref)
 
     xxt_ref[...] += jax.lax.dot_general(
-        x, x, (((1,), (1,)), ((), ())),
+        x, x, (((1,), (1,)), ((), ())), precision=_HIGHEST,
         preferred_element_type=jnp.float32)       # MXU diagonal tile
     x2 = x * x
     mom_ref[...] += jnp.stack([jnp.sum(x, axis=1), jnp.sum(x2, axis=1),
@@ -105,6 +108,7 @@ def stream_stats_fleet_pallas(x: jax.Array, kp: int, tn: int = DEFAULT_TN,
             jax.ShapeDtypeStruct((ek, 4), jnp.float32),
         ],
         interpret=interpret,
+        name="stream_stats_fleet",
     )(x)
     return mom, xxt
 
@@ -135,5 +139,6 @@ def stream_stats_pallas(x: jax.Array, tk: int = DEFAULT_TK,
             jax.ShapeDtypeStruct((k, 4), jnp.float32),
         ],
         interpret=interpret,
+        name="stream_stats",
     )(x, x)
     return mom, xxt

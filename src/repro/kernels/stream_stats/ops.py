@@ -7,14 +7,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.dispatch import resolve_use_kernel
 from repro.kernels.stream_stats.kernel import (DEFAULT_TK, DEFAULT_TN,
                                                stream_stats_fleet_pallas,
                                                stream_stats_pallas)
 from repro.kernels.stream_stats.ref import stream_stats_ref
-
-
-def _on_tpu() -> bool:
-    return jax.devices()[0].platform == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("use_kernel", "interpret"))
@@ -22,12 +19,12 @@ def window_moments_xxt(x: jax.Array, use_kernel: bool = True,
                        interpret: bool = False):
     """Raw power sums + cross products of a full window (k, N).
 
-    Zero-pads to tile multiples (exact for sums/products), dispatches to the
-    Pallas kernel on TPU (or interpret mode when requested) and the jnp
-    oracle otherwise.
+    Zero-pads to tile multiples (exact for sums/products) and runs the
+    Pallas kernel, or the jnp oracle when ``use_kernel`` is False
+    (None = auto, :func:`repro.kernels.dispatch.resolve_use_kernel`).
     """
     k, n = x.shape
-    if not use_kernel:
+    if not resolve_use_kernel(use_kernel, interpret):
         return stream_stats_ref(x)
     tk = min(DEFAULT_TK, max(1, k))
     tn = min(DEFAULT_TN, max(128, 1 << int(np.ceil(np.log2(max(n, 1))))))
@@ -48,14 +45,13 @@ def fleet_window_moments_xxt(x: jax.Array, use_kernel=None,
     one kernel launch for all E sites, computing only the E diagonal
     (kp, kp) tiles.  Off-kernel the vmapped jnp oracle is used.
     use_kernel=None means auto: the Pallas kernel on TPU (or under
-    ``interpret``), the oracle elsewhere.
+    ``interpret``), the oracle elsewhere
+    (:func:`repro.kernels.dispatch.resolve_use_kernel`).
 
     Returns (moments (E, k, 4), xxt (E, k, k)), both f32.
     """
     e, k, n = x.shape
-    if use_kernel is None:
-        use_kernel = _on_tpu() or interpret
-    if not use_kernel:
+    if not resolve_use_kernel(use_kernel, interpret):
         return jax.vmap(stream_stats_ref)(x)
     kp = int(np.ceil(k / 8) * 8)
     tn = min(DEFAULT_TN, max(128, 1 << int(np.ceil(np.log2(max(n, 1))))))

@@ -12,4 +12,4 @@ def stream_stats_ref(x: jax.Array):
     x2 = x * x
     mom = jnp.stack([x.sum(1), x2.sum(1), (x2 * x).sum(1), (x2 * x2).sum(1)],
                     axis=1)
-    return mom, x @ x.T
+    return mom, jnp.matmul(x, x.T, precision=jax.lax.Precision.HIGHEST)

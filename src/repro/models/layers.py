@@ -20,7 +20,8 @@ import numpy as np
 
 from repro.models.config import ModelConfig, MoEConfig, SSMConfig
 from repro.parallel.sharding import logical_sharding_constraint as shard
-from repro.parallel.sharding import shard_map_compat as _shard_map
+
+_shard_map = jax.shard_map
 
 Array = jax.Array
 

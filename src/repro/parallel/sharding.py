@@ -24,22 +24,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 _state = threading.local()
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs, axis_names,
-                     check_vma=False):
-    """``jax.shard_map`` across jax versions: the new API (axis_names /
-    check_vma) when present, else ``jax.experimental.shard_map``
-    (auto/check_rep).  Shared by the model stack (MoE dispatch) and the
-    fleet's sharded plan engine (repro.planning.sharded)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=axis_names,
-                             check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    auto = frozenset(mesh.axis_names) - set(axis_names)
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma, auto=auto)
-
-
 def site_mesh(n_devices: Optional[int] = None) -> Mesh:
     """1-D mesh over the fleet's embarrassingly-parallel site axis.
 

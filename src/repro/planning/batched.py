@@ -6,9 +6,10 @@ means E full round trips per window.  Here the fleet's windows are stacked
 into one ``(E, k, N)`` tensor and every stage runs batched:
 
   * window statistics — one block-diagonal ``stream_stats`` kernel pass over
-    the flattened (E·kp, N) layout (``fleet_window_moments_xxt``), with the
-    per-site dependence matrices extracted from the diagonal tiles and
-    derived moments via ``repro.core.stats.stats_from_sums``;
+    the flattened (E·kp, N) layout (``fleet_window_moments_xxt``) of the
+    mean-centred window, with the per-site dependence matrices extracted
+    from the diagonal tiles and derived moments via
+    ``repro.core.stats.stats_from_sums``;
   * predictor selection, compact-model fitting and the epsilon policy —
     vmapped over sites, for *every* registered model family (linear / cubic
     polynomials, mean imputation, the two-predictor multi model) through
@@ -84,11 +85,16 @@ def fleet_plan(values: Array, counts: Array, budgets: Array,
     e, k, n_max = values.shape
     cf = counts.astype(values.dtype)
     mask = (jnp.arange(n_max)[None, None, :] < cf[..., None]).astype(values.dtype)
-    xm = values * mask
+    # centre every stream on its window mean before the one-pass sums:
+    # fleet streams sit many spreads away from zero, where S2/n - mean^2,
+    # the S1..S4 fourth moment and XX^T/n - mean mean^T would cancel most
+    # of their f32 digits (the fourth moment entirely)
+    shift = jnp.sum(values * mask, axis=-1) / jnp.maximum(cf, 1.0)
+    xc = (values - shift[..., None]) * mask
 
-    mom, xxt = fleet_window_moments_xxt(xm, use_kernel=use_kernel,
+    mom, xxt = fleet_window_moments_xxt(xc, use_kernel=use_kernel,
                                         interpret=interpret)
-    stats = stats_mod.stats_from_sums(mom, xxt, counts)
+    stats = stats_mod.stats_from_sums(mom, xxt, counts, shift=shift)
     if dependence == "spearman":
         ranks = jax.vmap(stats_mod.rank_transform)(values, counts)
         rmom, rxxt = fleet_window_moments_xxt(ranks * mask,

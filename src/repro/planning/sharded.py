@@ -22,8 +22,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.api.registry import ENGINES
-from repro.parallel.sharding import (pad_site_axis, shard_map_compat,
-                                     site_mesh, site_pad)
+from repro.parallel.sharding import pad_site_axis, site_mesh, site_pad
 from repro.planning.batched import BatchedEngine, fleet_plan
 
 
@@ -40,10 +39,10 @@ def _sharded_plan_fn(device_ids, epsilon_scale, dependence, model,
         fleet_plan, epsilon_scale=epsilon_scale,
         dependence=dependence, model=model, epsilon_policy=epsilon_policy,
         use_kernel=use_kernel, interpret=interpret)
-    return jax.jit(shard_map_compat(
+    return jax.jit(jax.shard_map(
         plan_shard, mesh=mesh,
         in_specs=(P("sites"), P("sites"), P("sites")),
-        out_specs=P("sites"), axis_names={"sites"}))
+        out_specs=P("sites"), axis_names={"sites"}, check_vma=False))
 
 
 class ShardedEngine(BatchedEngine):

@@ -56,22 +56,48 @@ class CtrlParams:
         return tuple(np.sqrt(c).tolist())
 
 
+def ordered_sum(x):
+    """Sum over the last axis in one fixed order: a pairwise tree over the
+    axis zero-padded to a power of two, written as elementwise adds.
+
+    ``jnp.sum`` leaves the association to XLA, which picks it per program:
+    the same rows summed inside an E=1024 and an E=4096 program can differ
+    in the last bit on a TPU.  Where such a bit reaches a ``floor`` (the
+    controller's budgets), per-site results would depend on the fleet's
+    shape and on how it is sharded; elementwise adds of fixed slices
+    cannot be re-associated, so this sum is the same in every program.
+    """
+    n = x.shape[-1]
+    p = 1 << max(n - 1, 0).bit_length()
+    if p != n:
+        x = jnp.concatenate(
+            [x, jnp.zeros(x.shape[:-1] + (p - n,), x.dtype)], axis=-1)
+    while x.shape[-1] > 1:
+        x = x[..., 0::2] + x[..., 1::2]
+    return x[..., 0]
+
+
 def water_fill(demand, total: float, lo, hi, iters: int = 8,
                axis_name: Optional[str] = None):
     """jnp mirror of ``repro.fleet.controller.water_fill`` (unrolled).
 
-    ``axis_name`` (sharded scan runtime): the arrays are the local site
-    shard and every reduction becomes a global ``psum`` over the mesh axis
-    — the only cross-device traffic in the whole window step.  ``None``
-    (the default) emits the exact legacy single-device graph.
+    Every fleet sum is an :func:`ordered_sum`.  ``axis_name`` (sharded
+    scan runtime): the arrays are the local site shard and every reduction
+    becomes global over the mesh axis — the only cross-device traffic in
+    the whole window step.  Each device gathers the whole (E,) vector and
+    sums it in the single-device program's order, so sharded budgets are
+    bitwise those of one device.  (A ``psum`` of per-device partial sums
+    re-associates the fleet sum; on four TPU chips that moved budgets by
+    an ULP and flipped ``floor(budget)`` for some sites.)
     """
     if axis_name is None:
-        gsum = jnp.sum
+        gsum = ordered_sum
         def gany(x):                            # noqa: E306
             return jnp.any(x)
     else:
         def gsum(x):
-            return jax.lax.psum(jnp.sum(x), axis_name)
+            return ordered_sum(
+                jax.lax.all_gather(x, axis_name, tiled=True))
 
         def gany(x):
             return jax.lax.pmax(jnp.any(x).astype(jnp.int32), axis_name) > 0
@@ -107,7 +133,7 @@ def controller_budgets(state: ControllerState, p: CtrlParams, live=None,
     ``axis_name`` (sharded scan runtime): ``state``/``live`` hold the local
     site shard — shapes come from the state, not ``p.n_sites`` (which stays
     the *global* count so ``equal_share`` and the water-fill total keep
-    fleet-wide semantics) — and the water-fill reduces with ``psum``.
+    fleet-wide semantics) — and the water-fill sums over the whole mesh.
     """
     eq = p.equal_share
     e = state.demand.shape[0]        # local shard size under shard_map

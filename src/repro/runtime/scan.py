@@ -43,7 +43,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Optional
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -55,6 +55,19 @@ from repro.runtime.controller import CtrlParams
 from repro.runtime.state import init_state
 from repro.runtime.step import (PAYLOAD_PLAN_FIELDS, SCAN_QUERIES,
                                 make_window_step)
+
+
+class _Prepared(NamedTuple):
+    """One run's compiled scan fn and its placed arguments."""
+
+    fn: Callable
+    args: tuple                        # (state, xs, pool), on device
+    pool_np: np.ndarray
+    live_tbl: Optional[np.ndarray]
+    k: int
+    n: int
+    T: int
+    w0: int
 
 
 @dataclasses.dataclass
@@ -219,13 +232,14 @@ class ScanRuntime:
     # ------------------------------------------------- overridable plumbing
     # The sharded runtime (repro.runtime.sharded) reuses this run() driver
     # and specializes exactly four seams: how a resumed state enters the
-    # device (padding), which liveness table the step consumes (padding
-    # columns as permanently-dead sites), how the pool lands on device,
-    # and how results/state leave (slicing the padding back off).
+    # run (padding), which liveness table the step consumes (padding
+    # columns as permanently-dead sites), how state, inputs and pool land
+    # on the device(s), and how results/state leave (slicing the padding
+    # back off).
 
     def _adopt_state(self, state):
-        """A checkpointed RuntimeState entering this run's device layout."""
-        return jax.tree.map(jnp.asarray, state)
+        """A checkpointed RuntimeState entering this run's site layout."""
+        return state
 
     def _liveness_table(self, T: int, w0: int):
         """(T, run_sites) bool mask for the step, or None (all live)."""
@@ -235,31 +249,19 @@ class ScanRuntime:
         return liveness_table(self.chaos, T, self.n_sites,
                               self.topology.region_of(), first_window=w0)
 
-    def _device_pool(self, pool_np):
-        return jnp.asarray(pool_np)
+    def _place(self, state, xs, pool_np):
+        """Host (state, xs, pool) -> the scan fn's device arguments."""
+        return (jax.tree.map(jnp.asarray, state),
+                jax.tree.map(jnp.asarray, xs), jnp.asarray(pool_np))
 
     def _finalize(self, ys, state, live_tbl):
         """Host-side (ys, final_state, live_tbl) right after the scan."""
         return ys, state, live_tbl
 
     # ----------------------------------------------------------------- run
-    def run(self, windows, n_windows: Optional[int] = None, *,
-            state=None, first_window: Optional[int] = None) -> dict:
-        """windows: list of (E, k, N) arrays (fleet) or WindowBatch (E=1).
-
-        ``n_windows`` extends the run past the materialized pool by cycling
-        it (window ``wid`` reads pool slot ``wid % P``) — the sustained-
-        throughput configuration benchmarks use.
-
-        ``state``/``first_window`` resume a run from a checkpointed
-        :class:`~repro.runtime.state.RuntimeState` carry: window ids start
-        at ``first_window`` (default ``state.window_id`` — the cursor a
-        checkpoint froze) so RNG keys, pool slots and controller EWMAs
-        continue exactly where the saved run stopped; the result dict's
-        ``final_state`` holds the end-of-run carry for the next checkpoint.
-        Resuming is bit-for-bit: a full run equals any split of it
-        (tests/test_ckpt.py).
-        """
+    def _prepare(self, windows, n_windows, state, first_window):
+        """Stack the pool, build or adopt the carry, place everything and
+        fetch the compiled scan fn: what ``run`` and ``lower`` share."""
         single = self.topology is None
         if single:
             k = int(windows[0].k)
@@ -306,10 +308,41 @@ class ScanRuntime:
             state = dataclasses.replace(
                 state, chaos=make_chaos_carry(self._run_sites, k,
                                               self.query_names))
-        fn = self._scan_fn(static_exec)
-        pool = self._device_pool(pool_np)
-        wids = jnp.arange(w0, w0 + T, dtype=jnp.int32)
-        xs = wids if live_tbl is None else (wids, jnp.asarray(live_tbl))
+        wids = np.arange(w0, w0 + T, dtype=np.int32)
+        xs = wids if live_tbl is None else (wids, live_tbl)
+        return _Prepared(fn=self._scan_fn(static_exec),
+                         args=self._place(state, xs, pool_np),
+                         pool_np=pool_np, live_tbl=live_tbl, k=k, n=n, T=T,
+                         w0=w0)
+
+    def lower(self, windows, n_windows: Optional[int] = None):
+        """The ``jax.stages.Lowered`` scan program a fresh ``run`` over
+        these windows executes, its arguments placed as ``run`` places
+        them.  ``.compile().as_text()`` shows what runs on the device —
+        e.g. one ``tpu_custom_call`` per Pallas kernel on a TPU."""
+        prep = self._prepare(windows, n_windows, None, None)
+        return prep.fn.lower(*prep.args)
+
+    def run(self, windows, n_windows: Optional[int] = None, *,
+            state=None, first_window: Optional[int] = None) -> dict:
+        """windows: list of (E, k, N) arrays (fleet) or WindowBatch (E=1).
+
+        ``n_windows`` extends the run past the materialized pool by cycling
+        it (window ``wid`` reads pool slot ``wid % P``) — the sustained-
+        throughput configuration benchmarks use.
+
+        ``state``/``first_window`` resume a run from a checkpointed
+        :class:`~repro.runtime.state.RuntimeState` carry: window ids start
+        at ``first_window`` (default ``state.window_id`` — the cursor a
+        checkpoint froze) so RNG keys, pool slots and controller EWMAs
+        continue exactly where the saved run stopped; the result dict's
+        ``final_state`` holds the end-of-run carry for the next checkpoint.
+        Resuming is bit-for-bit: a full run equals any split of it
+        (tests/test_ckpt.py).
+        """
+        single = self.topology is None
+        fn, (state, xs, pool), pool_np, live_tbl, k, n, T, w0 = \
+            self._prepare(windows, n_windows, state, first_window)
 
         t0 = time.perf_counter()
         if self.mode == "scan":

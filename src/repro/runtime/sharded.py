@@ -12,32 +12,39 @@ site-sharded, donated :class:`~repro.runtime.state.RuntimeState` pytree
 its device between windows.
 
 Mesh layout / padding
-    E is rounded up to the device multiple with
-    :func:`~repro.parallel.sharding.pad_site_axis`; the extra rows are not
+    E is rounded up to the device multiple
+    (:func:`~repro.parallel.sharding.site_pad`); the extra rows are not
     a special case but ordinary *permanently dead* sites in the same
     liveness mask chaos faults use
     (:func:`~repro.chaos.padded_liveness_table`), so the step always runs
     its ``chaos=True`` body and every dead-site guarantee (zero budget,
-    zero bytes, frozen EWMAs, no ingest) covers padding for free.
+    zero bytes, frozen EWMAs, no ingest) covers padding for free.  Pool,
+    carry and liveness go from the host straight to their devices
+    (``jax.device_put`` on ``NamedSharding``s), never staged on one chip.
 
 Collective inventory (per window, rebalance controller only)
-    ``water_fill`` — 2 + 2·iters ``psum`` of scalars (the budget
-    redistribution is the one genuinely fleet-global computation);
-    adaptive runs add one ``pmax`` for the drift gate's deviation max.
+    ``water_fill`` — 1 + 2·iters ``all_gather`` of the (E,) vectors it
+    sums plus one ``pmax`` (the budget redistribution is the one
+    genuinely fleet-global computation); every device sums the gathered
+    vector in the single-device order
+    (:func:`~repro.runtime.controller.ordered_sum`).  Adaptive runs add
+    one ``pmax`` for the drift gate's deviation max.
     Static-budget runs are collective-free: the whole window step is then
     embarrassingly parallel, like the sharded plan engine.
 
 Parity contract (pinned in tests/test_scan_runtime.py under 8 forced
 host devices)
     Counters, WAN bytes and sample sets match the batched scan *bitwise*
-    — budgets are host-f64 (static) or psum'd (rebalance), n_real is
-    integer, and the sampler consumes the batched run's exact global
-    uniforms (each device draws the full unpadded-(E, k, N) tensor and
-    slices its rows; threefry is not prefix-stable across shapes, so
-    replicated generation is the price of bitwise RNG parity).  Float
-    tables (estimates, EWMAs under rebalance) carry the documented f32
-    class: XLA re-associates reductions across shard boundaries exactly
-    as it does across scan/steps mode (docs/runtime.md).
+    on any number of devices — budgets are host-f64 (static) or
+    water-filled with ordered sums of the gathered fleet (rebalance), the
+    controller's error signal is an ordered sum too, n_real is integer,
+    and the sampler consumes the batched run's exact global uniforms
+    (each device draws the full unpadded-(E, k, N) tensor and slices its
+    rows; threefry is not prefix-stable across shapes, so replicated
+    generation is the price of bitwise RNG parity).  Other float tables
+    (fit coefficients) carry the documented f32 class: XLA may associate
+    a reduction differently in a program of another shape
+    (docs/runtime.md).
 
 Checkpoints stay *unpadded*: ``final_state`` is sliced back to E sites, so
 sharded and batched checkpoints are interchangeable in both directions —
@@ -51,10 +58,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.parallel.sharding import (pad_site_axis, shard_map_compat,
-                                     site_mesh, site_pad)
+from repro.parallel.sharding import site_mesh, site_pad
 from repro.runtime.scan import ScanRuntime
 from repro.runtime.step import make_window_step
 
@@ -148,10 +154,11 @@ class ShardedScanRuntime(ScanRuntime):
 
             def fn(state, xs, pool):
                 specs = self._state_specs(state)
-                sm = shard_map_compat(
+                sm = jax.shard_map(
                     body, mesh=mesh,
                     in_specs=(specs, (P(), P(None, AXIS)), P(None, AXIS)),
-                    out_specs=(specs, P(None, AXIS)), axis_names={AXIS})
+                    out_specs=(specs, P(None, AXIS)), axis_names={AXIS},
+                    check_vma=False)
                 return sm(state, xs, pool)
 
             self._fns[static_exec] = jax.jit(fn, donate_argnums=0)
@@ -165,9 +172,10 @@ class ShardedScanRuntime(ScanRuntime):
         e, e_pad = self.n_sites, self._run_sites
 
         def pad(x):
-            x = jnp.asarray(x)
+            x = np.asarray(x)
             if e_pad != e and x.ndim >= 1 and x.shape[0] == e:
-                return pad_site_axis(x, e_pad)
+                return np.concatenate(
+                    [x, np.zeros((e_pad - e,) + x.shape[1:], x.dtype)])
             return x
 
         return jax.tree.map(pad, state)
@@ -180,14 +188,24 @@ class ShardedScanRuntime(ScanRuntime):
                                      self.topology.region_of(),
                                      first_window=w0)
 
-    def _device_pool(self, pool_np):
+    def _place(self, state, xs, pool_np):
+        """Each device receives only its own site shard, straight from the
+        host: pool and liveness split on their site axis, the carry by
+        :meth:`_state_specs`, window ids replicated."""
         pad = self._run_sites - self.n_sites
         if pad:
             pool_np = np.concatenate(
                 [pool_np, np.zeros((pool_np.shape[0], pad)
                                    + pool_np.shape[2:], pool_np.dtype)],
                 axis=1)
-        return jnp.asarray(pool_np)
+
+        def put(x, spec):
+            return jax.device_put(x, NamedSharding(self._mesh, spec))
+
+        state = jax.tree.map(put, state, self._state_specs(state))
+        wids, live = xs
+        xs = (put(wids, P()), put(live, P(None, AXIS)))
+        return state, xs, put(pool_np, P(None, AXIS))
 
     def _finalize(self, ys, state, live_tbl):
         """Slice padding off every output; hand back a state a *batched*

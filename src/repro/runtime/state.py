@@ -22,7 +22,7 @@ import dataclasses
 from typing import Any, Optional
 
 import jax
-import jax.numpy as jnp
+import numpy as np
 
 from repro.core.types import Array
 
@@ -70,18 +70,21 @@ class RuntimeState:
 
 
 def init_state(n_sites: int, k: int, equal_share: float) -> RuntimeState:
-    """Fresh state matching ``BudgetController.__post_init__`` semantics."""
+    """Fresh state matching ``BudgetController.__post_init__`` semantics.
+
+    Host (numpy) leaves: the runtime places them itself — on one device,
+    or shard by shard across the site mesh."""
     e = n_sites
     return RuntimeState(
-        window_id=jnp.asarray(0, jnp.int32),
+        window_id=np.asarray(0, np.int32),
         controller=ControllerState(
-            demand=jnp.ones((e,), jnp.float32),
-            r2=jnp.zeros((e,), jnp.float32),
-            lag=jnp.zeros((e,), jnp.float32),
-            lag_seen=jnp.zeros((e,), bool),
-            seen=jnp.asarray(False),
-            last_budgets=jnp.full((e,), equal_share, jnp.float32)),
+            demand=np.ones((e,), np.float32),
+            r2=np.zeros((e,), np.float32),
+            lag=np.zeros((e,), np.float32),
+            lag_seen=np.zeros((e,), bool),
+            seen=np.asarray(False),
+            last_budgets=np.full((e,), equal_share, np.float32)),
         totals=StreamTotals(
-            count=jnp.zeros((e, k), jnp.float32),
-            s1=jnp.zeros((e, k), jnp.float32),
-            s2=jnp.zeros((e, k), jnp.float32)))
+            count=np.zeros((e, k), np.float32),
+            s1=np.zeros((e, k), np.float32),
+            s2=np.zeros((e, k), np.float32)))

@@ -34,7 +34,7 @@ import numpy as np
 
 from repro.planning.batched import FleetPlan
 from repro.runtime.controller import (CtrlParams, controller_budgets,
-                                      controller_update)
+                                      controller_update, ordered_sum)
 from repro.runtime.state import RuntimeState, StreamTotals
 
 # per-stream model upload footprint, matching EdgePayload.wan_bytes():
@@ -215,16 +215,19 @@ def _masked_queries(parts, qnames):
     parts: list of (values (E, k, N), mask (E, k, N) bool) making up each
     stream's reconstruction (real ++ imputed).  AVG/VAR use the stable
     two-pass form; VAR is ddof=1; empty -> NaN, single sample VAR -> NaN.
+    Sums over the window are :func:`ordered_sum`s, so a site's answers
+    (and the controller's error signal built on AVG) do not depend on the
+    fleet's size or sharding.
     """
     tot = sum(m.sum(-1) for _, m in parts).astype(jnp.float32)
-    s1 = sum(jnp.where(m, x, 0.0).sum(-1) for x, m in parts)
+    s1 = sum(ordered_sum(jnp.where(m, x, 0.0)) for x, m in parts)
     avg = jnp.where(tot > 0, s1 / jnp.maximum(tot, 1.0), jnp.nan)
     out = {}
     for q in qnames:
         if q == "AVG":
             out[q] = avg
         elif q == "VAR":
-            ss = sum((jnp.where(m, x - avg[..., None], 0.0) ** 2).sum(-1)
+            ss = sum(ordered_sum(jnp.where(m, x - avg[..., None], 0.0) ** 2)
                      for x, m in parts)
             out[q] = jnp.where(tot > 1, ss / jnp.maximum(tot - 1.0, 1.0),
                                jnp.nan)
@@ -288,7 +291,8 @@ def make_window_step(pool, *, seed: int, plan_fn, qnames, multi: bool,
     :mod:`repro.runtime.sharded`): the step body is being traced inside
     ``shard_map`` over a 1-D site mesh, so ``pool``/``state``/``live``
     hold only the local site shard.  ``axis_name`` routes the two
-    fleet-global reductions — the water-fill sums (psum) and the adaptive
+    fleet-global reductions — the water-fill sums (all-gather, then the
+    single-device order) and the adaptive
     gate's deviation max (pmax) — across the mesh; everything else in the
     step is per-site and stays collective-free.  ``sample_slice``
     ``(e_rng, e_pad, offset)`` makes the Fisher-Yates draw consume the
@@ -391,7 +395,10 @@ def make_window_step(pool, *, seed: int, plan_fn, qnames, multi: bool,
         if t_avg is None:
             t_avg = _masked_queries([(values, full_mask)], ("AVG",))["AVG"]
         rel = jnp.abs(e_avg - t_avg) / jnp.maximum(jnp.abs(t_avg), 1e-6)
-        obs_err = jnp.nanmean(rel, axis=1)
+        seen = ~jnp.isnan(rel)           # nanmean over streams, fixed order
+        n_seen = seen.sum(-1)
+        obs_err = jnp.where(n_seen > 0, ordered_sum(jnp.where(seen, rel, 0.0))
+                            / jnp.maximum(n_seen, 1), jnp.nan)
 
         ctrl2 = controller_update(state.controller, ctrl, raw_b, obs_err,
                                   plan.r2, plan.objective, live=live)

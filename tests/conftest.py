@@ -9,6 +9,8 @@ import numpy as np
 import pytest
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
+# the repo root: chip_smoke.py's phases are tested on the CPU
+sys.path.insert(1, os.path.join(os.path.dirname(__file__), ".."))
 
 # ---------------------------------------------------------------------------
 # hypothesis fallback: several test modules import `hypothesis` at module
@@ -96,7 +98,11 @@ def run_matrix(values, window, budget_fraction, method, cfg=None,
 
 
 def subprocess_env(n_devices: int) -> dict:
+    """Environment for a child interpreter on ``n_devices`` forced host CPU
+    devices.  ``JAX_PLATFORMS=cpu`` keeps the child off any accelerator:
+    the parent test process may already hold it."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     return env

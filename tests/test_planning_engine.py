@@ -21,6 +21,7 @@ import subprocess
 import sys
 import textwrap
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -235,8 +236,7 @@ def _shrink_f64(nr, ns, sigma2, v, cap):
     """Run the jnp shrink in f64 so the IEEE arithmetic matches the f64
     reference loop exactly (the production path runs it in the planner's
     f32; the grid tests above cover that end to end)."""
-    from jax.experimental import enable_x64
-    with enable_x64(True):
+    with jax.enable_x64(True):
         return np.asarray(eps_mod.exact_mse_shrink(
             jnp.asarray(nr, jnp.float64), jnp.asarray(ns, jnp.float64),
             jnp.asarray(sigma2, jnp.float64), jnp.asarray(v, jnp.float64),

@@ -653,3 +653,24 @@ def test_sharded_runtime_construction_rejections():
         _sharded_scenario("scan_sharded")).runtime
     with pytest.raises(ValueError, match="pad_sites"):
         dataclasses.replace(rt, pad_sites=_SHARDED_E - 2)
+
+
+@pytest.mark.parametrize("n", [1, 5, 288, 1024])
+def test_ordered_sum_is_one_fixed_pairwise_tree(n):
+    """The controller's sums: a pairwise tree over the zero-padded axis,
+    bit for bit, and the same bits for a row alone or inside a batch (the
+    property that keeps budgets equal across fleet shapes and meshes)."""
+    from repro.runtime.controller import ordered_sum
+    x = np.random.default_rng(n).normal(50.0, 4.0, (16, n)).astype(
+        np.float32)
+    tree = np.concatenate(
+        [x, np.zeros((16, (1 << (n - 1).bit_length()) - n), np.float32)], 1)
+    while tree.shape[1] > 1:
+        tree = tree[:, 0::2] + tree[:, 1::2]
+    batched = np.asarray(jax.jit(ordered_sum)(jnp.asarray(x)))
+    np.testing.assert_array_equal(batched, tree[:, 0])
+    for i in (0, 7, 15):
+        np.testing.assert_array_equal(
+            np.asarray(ordered_sum(jnp.asarray(x[i:i + 1]))), batched[i:i + 1])
+    np.testing.assert_allclose(batched, x.astype(np.float64).sum(1),
+                               rtol=1e-6)
