@@ -58,10 +58,11 @@ from repro.runtime.step import (PAYLOAD_PLAN_FIELDS, SCAN_QUERIES,
 
 
 class _Prepared(NamedTuple):
-    """One run's compiled scan fn and its placed arguments."""
+    """One run's compiled scan fn and its host arguments, not yet placed."""
 
     fn: Callable
-    args: tuple                        # (state, xs, pool), on device
+    state: "RuntimeState"
+    xs: object                         # wids, or (wids, live rows)
     pool_np: np.ndarray
     live_tbl: Optional[np.ndarray]
     k: int
@@ -142,11 +143,11 @@ class ScanRuntime:
                                      for s in self.topology.sites])
         else:
             self._cost = np.ones(1)
-        self.plan_seconds = 0.0
         self._fns = {}                 # static_exec key -> jitted scan fn
         # site rows the compiled step carries: == n_sites here; the sharded
         # runtime overrides it with E padded to the device multiple
         self._run_sites = self.n_sites
+        self._calls = 0                # run calls so far: the spans' `call`
 
     @classmethod
     def from_scenario(cls, scenario, *, use_kernel=None, interpret=False,
@@ -260,8 +261,8 @@ class ScanRuntime:
 
     # ----------------------------------------------------------------- run
     def _prepare(self, windows, n_windows, state, first_window):
-        """Stack the pool, build or adopt the carry, place everything and
-        fetch the compiled scan fn: what ``run`` and ``lower`` share."""
+        """Stack the pool, build or adopt the carry and fetch the compiled
+        scan fn: what ``run`` and ``lower`` share before ``_place``."""
         single = self.topology is None
         if single:
             k = int(windows[0].k)
@@ -310,8 +311,7 @@ class ScanRuntime:
                                               self.query_names))
         wids = np.arange(w0, w0 + T, dtype=np.int32)
         xs = wids if live_tbl is None else (wids, live_tbl)
-        return _Prepared(fn=self._scan_fn(static_exec),
-                         args=self._place(state, xs, pool_np),
+        return _Prepared(fn=self._scan_fn(static_exec), state=state, xs=xs,
                          pool_np=pool_np, live_tbl=live_tbl, k=k, n=n, T=T,
                          w0=w0)
 
@@ -321,7 +321,7 @@ class ScanRuntime:
         them.  ``.compile().as_text()`` shows what runs on the device —
         e.g. one ``tpu_custom_call`` per Pallas kernel on a TPU."""
         prep = self._prepare(windows, n_windows, None, None)
-        return prep.fn.lower(*prep.args)
+        return prep.fn.lower(*self._place(prep.state, prep.xs, prep.pool_np))
 
     def run(self, windows, n_windows: Optional[int] = None, *,
             state=None, first_window: Optional[int] = None) -> dict:
@@ -339,41 +339,59 @@ class ScanRuntime:
         ``final_state`` holds the end-of-run carry for the next checkpoint.
         Resuming is bit-for-bit: a full run equals any split of it
         (tests/test_ckpt.py).
+
+        Each call writes five host spans on the profiler's clock, in turn:
+        ``scan.prepare``, ``scan.place``, ``scan.execute`` (dispatch and
+        wait), ``scan.readback`` and ``scan.report``, each with the args
+        ``call`` (this runtime's count of ``run`` calls) and ``windows``
+        (T); see docs/runtime.md, "Tracing a serving process".
         """
         single = self.topology is None
-        fn, (state, xs, pool), pool_np, live_tbl, k, n, T, w0 = \
-            self._prepare(windows, n_windows, state, first_window)
+        self._calls += 1
+        T = len(windows) if n_windows is None else int(n_windows)
 
-        t0 = time.perf_counter()
-        if self.mode == "scan":
-            state, ys = fn(state, xs, pool)
-        else:
-            chunks = []
-            for w in range(T):
-                state, y = fn(state, jax.tree.map(lambda a: a[w:w + 1], xs),
-                              pool)
-                chunks.append(y)
-            ys = jax.tree.map(lambda *xs_: jnp.concatenate(xs_), *chunks)
-        ys = jax.block_until_ready(ys)
-        scan_seconds = time.perf_counter() - t0
-        self.plan_seconds += scan_seconds
-        ys = jax.tree.map(np.asarray, ys)
-        state = jax.tree.map(np.asarray, state)
-        ys, state, live_tbl = self._finalize(ys, state, live_tbl)
+        def span(name):
+            return jax.profiler.TraceAnnotation(name, call=self._calls,
+                                                windows=T)
 
-        if self.collect == "payloads":
-            est, tru, bytes_site, cost_site = self._replay(
-                ys, pool_np, T, windows, w0=w0, live_tbl=live_tbl)
-        else:
-            est = {q: np.asarray(ys["est"][q], np.float64)
-                   for q in self.query_names}
-            tru = {q: np.asarray(ys["tru"][q], np.float64)
-                   for q in self.query_names}
-            bytes_site = ys["bytes"].astype(np.int64).sum(axis=0)
-            cost_site = bytes_site * self._cost
-            if single:
-                est = {q: v[:, 0] for q, v in est.items()}
-                tru = {q: v[:, 0] for q, v in tru.items()}
+        with span("scan.prepare"):
+            fn, state, xs, pool_np, live_tbl, k, n, T, w0 = \
+                self._prepare(windows, n_windows, state, first_window)
+        with span("scan.place"):
+            state, xs, pool = self._place(state, xs, pool_np)
+
+        with span("scan.execute"):
+            t0 = time.perf_counter()
+            if self.mode == "scan":
+                state, ys = fn(state, xs, pool)
+            else:
+                chunks = []
+                for w in range(T):
+                    state, y = fn(state,
+                                  jax.tree.map(lambda a: a[w:w + 1], xs),
+                                  pool)
+                    chunks.append(y)
+                ys = jax.tree.map(lambda *xs_: jnp.concatenate(xs_), *chunks)
+            ys = jax.block_until_ready(ys)
+            scan_seconds = time.perf_counter() - t0
+
+        with span("scan.readback"):
+            ys = jax.tree.map(np.asarray, ys)
+            state = jax.tree.map(np.asarray, state)
+            ys, state, live_tbl = self._finalize(ys, state, live_tbl)
+            if self.collect == "payloads":
+                est, tru, bytes_site, cost_site = self._replay(
+                    ys, pool_np, T, windows, w0=w0, live_tbl=live_tbl)
+            else:
+                est = {q: np.asarray(ys["est"][q], np.float64)
+                       for q in self.query_names}
+                tru = {q: np.asarray(ys["tru"][q], np.float64)
+                       for q in self.query_names}
+                bytes_site = ys["bytes"].astype(np.int64).sum(axis=0)
+                cost_site = bytes_site * self._cost
+                if single:
+                    est = {q: v[:, 0] for q, v in est.items()}
+                    tru = {q: v[:, 0] for q, v in tru.items()}
 
         extras = {
             "final_state": state,
@@ -388,12 +406,13 @@ class ScanRuntime:
                          ("budgets", "obs_err", "r2", "objective")},
             "bytes_history": ys["bytes"],
         }
-        if single:
-            return self._result_single(est, tru, bytes_site, cost_site, T,
-                                       k, n, scan_seconds, extras)
-        return self._result_fleet(est, tru, bytes_site, cost_site, ys,
-                                  state, T, k, n, scan_seconds, extras,
-                                  live_tbl=live_tbl)
+        with span("scan.report"):
+            if single:
+                return self._result_single(est, tru, bytes_site, cost_site,
+                                           T, k, n, scan_seconds, extras)
+            return self._result_fleet(est, tru, bytes_site, cost_site, ys,
+                                      state, T, k, n, scan_seconds, extras,
+                                      live_tbl=live_tbl)
 
     # ------------------------------------------------------------- results
     def _replay(self, ys, pool_np, T, windows, w0: int = 0, live_tbl=None):

@@ -298,6 +298,12 @@ def make_window_step(pool, *, seed: int, plan_fn, qnames, multi: bool,
     ``(e_rng, e_pad, offset)`` makes the Fisher-Yates draw consume the
     batched run's exact global uniforms (see :func:`_fy_sample`).  Both
     default to None, which traces the unchanged single-device graph.
+
+    Each stage runs under a ``jax.named_scope`` (``step.budgets``,
+    ``step.gate``, ``step.plan``, ``step.sample``, ``step.impute``,
+    ``step.queries``, ``step.truth``, ``step.update``): op metadata only,
+    so a device trace can be split by stage and the computation is the
+    same (docs/runtime.md, "Tracing a serving process").
     """
     p_, e, k, n = pool.shape
     counts = jnp.full((e, k), n, jnp.int32)
@@ -316,55 +322,65 @@ def make_window_step(pool, *, seed: int, plan_fn, qnames, multi: bool,
             wid, live = xs, None
         values = jax.lax.dynamic_index_in_dim(pool, jnp.mod(wid, p_),
                                               keepdims=False)
-        raw_b = controller_budgets(state.controller, ctrl, live=live,
-                                   axis_name=axis_name)
-        if static_exec_budgets is not None:
-            budgets = static_exec if live is None else static_exec * livf
-        elif live is None:
-            budgets = jnp.maximum(jnp.floor(raw_b), 2.0)
-        else:
-            # the >=2 clamp would resurrect dead sites' zero budgets
-            budgets = jnp.where(live, jnp.maximum(jnp.floor(raw_b), 2.0),
-                                0.0)
+        with jax.named_scope("step.budgets"):
+            raw_b = controller_budgets(state.controller, ctrl, live=live,
+                                       axis_name=axis_name)
+            if static_exec_budgets is not None:
+                budgets = static_exec if live is None else static_exec * livf
+            elif live is None:
+                budgets = jnp.maximum(jnp.floor(raw_b), 2.0)
+            else:
+                # the >=2 clamp would resurrect dead sites' zero budgets
+                budgets = jnp.where(live,
+                                    jnp.maximum(jnp.floor(raw_b), 2.0), 0.0)
 
         if adaptive is None:
-            plan = plan_fn(values, counts, budgets)
+            with jax.named_scope("step.plan"):
+                plan = plan_fn(values, counts, budgets)
             adaptive_carry = state.adaptive
         else:
             from repro.adaptive import AdaptiveCarry, gate_update
-            gate, replan = gate_update(adaptive, state.adaptive.gate,
-                                       values, counts,
-                                       use_kernel=use_kernel,
-                                       interpret=interpret,
-                                       axis_name=axis_name)
-            if (adaptive.detector == "always"
-                    and int(adaptive.min_replan_interval) == 1):
-                # the cond is statically always-true; planning unwrapped
-                # keeps XLA's fusion of the plan reductions identical to
-                # the plan-every-window body (the bitwise parity pin)
-                plan = plan_fn(values, counts, budgets)
-            else:
-                plan = jax.lax.cond(
-                    replan,
-                    lambda: plan_fn(values, counts, budgets),
-                    lambda: state.adaptive.plan)
+            with jax.named_scope("step.gate"):
+                gate, replan = gate_update(adaptive, state.adaptive.gate,
+                                           values, counts,
+                                           use_kernel=use_kernel,
+                                           interpret=interpret,
+                                           axis_name=axis_name)
+            with jax.named_scope("step.plan"):
+                if (adaptive.detector == "always"
+                        and int(adaptive.min_replan_interval) == 1):
+                    # the cond is statically always-true; planning
+                    # unwrapped keeps XLA's fusion of the plan reductions
+                    # identical to the plan-every-window body (the bitwise
+                    # parity pin)
+                    plan = plan_fn(values, counts, budgets)
+                else:
+                    plan = jax.lax.cond(
+                        replan,
+                        lambda: plan_fn(values, counts, budgets),
+                        lambda: state.adaptive.plan)
             adaptive_carry = AdaptiveCarry(gate=gate, plan=plan)
         if live is not None:
             # closed_form_alloc floors every stream at 1 sample even on a
             # zero budget; dead sites must truly ship nothing.  Masking
             # n_real leaves live rows' FY draws bitwise intact (the
             # shuffle's stop = max(n_real) still covers every live row).
-            plan = dataclasses.replace(
-                plan, n_real=plan.n_real * live[:, None].astype(
-                    plan.n_real.dtype))
-        samples = sample_fleet(seed, wid, values, plan.n_real,
-                               sample_slice=sample_slice)
-        imputed, ns, mask_i = _impute(plan, samples, plan.n_real,
-                                      multi=multi, mean=mean)
-        mask_r = jnp.arange(n)[None, None, :] < plan.n_real[..., None]
-
-        est = _masked_queries([(samples, mask_r), (imputed, mask_i)], qnames)
-        tru = _masked_queries([(values, full_mask)], qnames)
+            with jax.named_scope("step.plan"):
+                plan = dataclasses.replace(
+                    plan, n_real=plan.n_real * live[:, None].astype(
+                        plan.n_real.dtype))
+        with jax.named_scope("step.sample"):
+            samples = sample_fleet(seed, wid, values, plan.n_real,
+                                   sample_slice=sample_slice)
+        with jax.named_scope("step.impute"):
+            imputed, ns, mask_i = _impute(plan, samples, plan.n_real,
+                                          multi=multi, mean=mean)
+        with jax.named_scope("step.queries"):
+            mask_r = jnp.arange(n)[None, None, :] < plan.n_real[..., None]
+            est = _masked_queries([(samples, mask_r), (imputed, mask_i)],
+                                  qnames)
+        with jax.named_scope("step.truth"):
+            tru = _masked_queries([(values, full_mask)], qnames)
 
         if live is None:
             served = est
@@ -373,46 +389,53 @@ def make_window_step(pool, *, seed: int, plan_fn, qnames, multi: bool,
             # gap-serving: dead rows answer from the freshest estimate
             # that ever arrived (ReorderCloudNode.serve semantics); live
             # rows refresh the memory
-            served = {q: jnp.where(live[:, None], est[q],
-                                   state.chaos.served[q])
-                      for q in qnames}
+            with jax.named_scope("step.queries"):
+                served = {q: jnp.where(live[:, None], est[q],
+                                       state.chaos.served[q])
+                          for q in qnames}
             from repro.chaos import ChaosCarry
             chaos_carry = ChaosCarry(live=live, served=served)
 
-        # WAN accounting — EdgePayload.wan_bytes() per site
-        nbytes = (4 * plan.n_real.sum(-1) + header
-                  + per_model * (ns > 0).sum(-1)).astype(jnp.int32)
-        if live is not None:
-            # a dark site ships nothing, not even the header
-            nbytes = jnp.where(live, nbytes, 0)
+        with jax.named_scope("step.update"):
+            # WAN accounting — EdgePayload.wan_bytes() per site
+            nbytes = (4 * plan.n_real.sum(-1) + header
+                      + per_model * (ns > 0).sum(-1)).astype(jnp.int32)
+            if live is not None:
+                # a dark site ships nothing, not even the header
+                nbytes = jnp.where(live, nbytes, 0)
 
-        # edge-local error proxy -> controller (FleetRuntime.run semantics)
-        e_avg = est.get("AVG")
-        if e_avg is None:
-            e_avg = _masked_queries([(samples, mask_r), (imputed, mask_i)],
-                                    ("AVG",))["AVG"]
+            # edge-local error proxy -> controller (FleetRuntime.run
+            # semantics)
+            e_avg = est.get("AVG")
+            if e_avg is None:
+                e_avg = _masked_queries(
+                    [(samples, mask_r), (imputed, mask_i)], ("AVG",))["AVG"]
         t_avg = tru.get("AVG")
         if t_avg is None:
-            t_avg = _masked_queries([(values, full_mask)], ("AVG",))["AVG"]
-        rel = jnp.abs(e_avg - t_avg) / jnp.maximum(jnp.abs(t_avg), 1e-6)
-        seen = ~jnp.isnan(rel)           # nanmean over streams, fixed order
-        n_seen = seen.sum(-1)
-        obs_err = jnp.where(n_seen > 0, ordered_sum(jnp.where(seen, rel, 0.0))
-                            / jnp.maximum(n_seen, 1), jnp.nan)
+            with jax.named_scope("step.truth"):
+                t_avg = _masked_queries([(values, full_mask)],
+                                        ("AVG",))["AVG"]
+        with jax.named_scope("step.update"):
+            rel = jnp.abs(e_avg - t_avg) / jnp.maximum(jnp.abs(t_avg), 1e-6)
+            seen = ~jnp.isnan(rel)       # nanmean over streams, fixed order
+            n_seen = seen.sum(-1)
+            obs_err = jnp.where(
+                n_seen > 0, ordered_sum(jnp.where(seen, rel, 0.0))
+                / jnp.maximum(n_seen, 1), jnp.nan)
 
-        ctrl2 = controller_update(state.controller, ctrl, raw_b, obs_err,
-                                  plan.r2, plan.objective, live=live)
-        if live is None:
-            totals = StreamTotals(
-                count=state.totals.count + n,
-                s1=state.totals.s1 + values.sum(-1),
-                s2=state.totals.s2 + (values * values).sum(-1))
-        else:                        # dead sites ingest nothing
-            lcol = livf[:, None]
-            totals = StreamTotals(
-                count=state.totals.count + n * lcol,
-                s1=state.totals.s1 + values.sum(-1) * lcol,
-                s2=state.totals.s2 + (values * values).sum(-1) * lcol)
+            ctrl2 = controller_update(state.controller, ctrl, raw_b, obs_err,
+                                      plan.r2, plan.objective, live=live)
+            if live is None:
+                totals = StreamTotals(
+                    count=state.totals.count + n,
+                    s1=state.totals.s1 + values.sum(-1),
+                    s2=state.totals.s2 + (values * values).sum(-1))
+            else:                    # dead sites ingest nothing
+                lcol = livf[:, None]
+                totals = StreamTotals(
+                    count=state.totals.count + n * lcol,
+                    s1=state.totals.s1 + values.sum(-1) * lcol,
+                    s2=state.totals.s2 + (values * values).sum(-1) * lcol)
         new_state = RuntimeState(window_id=wid + 1, controller=ctrl2,
                                  totals=totals, adaptive=adaptive_carry,
                                  chaos=chaos_carry)

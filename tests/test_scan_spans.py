@@ -1,0 +1,86 @@
+"""The scan runtime's host spans and the window step's stage scopes.
+
+``ScanRuntime.run`` writes one ``TraceAnnotation`` per host phase, each
+with its call's number and window count, and ``make_window_step`` opens one
+``named_scope`` per stage, which reaches the compiled program only as op
+metadata (docs/runtime.md, "Tracing a serving process")."""
+import glob
+import os
+import re
+
+import jax
+import numpy as np
+import pytest
+from jax.profiler import ProfileData
+
+from repro.adaptive import AdaptiveSpec
+from repro.api import (ControllerSpec, DataSpec, Experiment, ScenarioConfig,
+                       TopologySpec)
+from repro.core.types import PlannerConfig
+
+E, K, N = 8, 3, 32
+PHASES = ["scan.prepare", "scan.place", "scan.execute", "scan.readback",
+          "scan.report"]
+STAGES = {"step.budgets", "step.plan", "step.sample", "step.impute",
+          "step.queries", "step.truth", "step.update"}
+
+
+def _runtime(mode="rebalance", adaptive=None, collect="estimates"):
+    scenario = ScenarioConfig(
+        name="spans",
+        data=DataSpec(dataset="fleet", n_points=2 * N, window=N, seed=1,
+                      options={"k": K}),
+        planner=PlannerConfig(solver="closed_form", seed=3),
+        topology=TopologySpec(n_regions=2, sites_per_region=E // 2, seed=0,
+                              latency_scale=0.0),
+        controller=ControllerSpec(mode=mode),
+        queries=("AVG", "VAR", "MIN", "MAX"), adaptive=adaptive,
+        runtime="scan")
+    rt = Experiment.from_scenario(scenario).runtime
+    rt.collect = collect
+    return rt
+
+
+def _windows(T):
+    rng = np.random.default_rng(0)
+    return [rng.normal(size=(E, K, N)).astype(np.float32) for _ in range(T)]
+
+
+def _spans(log_dir):
+    (path,) = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                        recursive=True)
+    return sorted(((ev.name, ev.start_ns, ev.end_ns, dict(ev.stats))
+                   for plane in ProfileData.from_file(path).planes
+                   for line in plane.lines for ev in line.events
+                   if ev.name.startswith("scan.")), key=lambda s: s[1])
+
+
+@pytest.mark.parametrize("collect", ["estimates", "payloads"])
+def test_run_writes_five_phase_spans_a_call(tmp_path, collect):
+    rt = _runtime(collect=collect)
+    w = _windows(2)
+    first = rt.run(w, n_windows=2)                # compiles, untraced
+    with jax.profiler.trace(str(tmp_path)):
+        res = rt.run(w, n_windows=2, state=first["final_state"])
+        rt.run(w[:1], n_windows=1, state=res["final_state"])
+    spans = _spans(tmp_path)
+    assert [s[0] for s in spans] == PHASES * 2
+    assert all(a[2] <= b[1] for a, b in zip(spans, spans[1:]))
+    assert all(s[1] <= s[2] for s in spans)
+    assert [s[3] for s in spans] == (
+        [{"call": 2, "windows": 2}] * 5 + [{"call": 3, "windows": 1}] * 5)
+    assert res["scan_seconds"] * 1e9 <= spans[2][2] - spans[2][1]
+    assert res["windows_per_sec"] == 2 / res["scan_seconds"]
+    assert not hasattr(rt, "plan_seconds")
+
+
+@pytest.mark.parametrize("mode,adaptive,stages", [
+    ("rebalance", None, STAGES),
+    ("static", None, STAGES - {"step.budgets"}),
+    ("rebalance", AdaptiveSpec(detector="threshold"), STAGES | {"step.gate"}),
+])
+def test_the_compiled_step_names_every_stage(mode, adaptive, stages):
+    rt = _runtime(mode=mode, adaptive=adaptive)
+    text = rt.lower(_windows(2), 2).compile().as_text()
+    named = set(re.findall(r'op_name="[^"]*?(step\.[a-z]+)/', text))
+    assert named == stages
