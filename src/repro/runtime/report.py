@@ -11,6 +11,7 @@ construction rather than by duplication.
 """
 from __future__ import annotations
 
+import jax
 import numpy as np
 
 from repro.core import queries as Q
@@ -36,23 +37,33 @@ def aggregate_fleet(*, topology, qnames, est, est_q, tru, ages,
     ``chaos``: the recovery/degradation metric dict from
     ``repro.chaos.chaos_metrics`` or None — merged under the same
     only-when-present contract.
+
+    The per-site NRMSE tables are one :func:`repro.core.queries.nrmse_rows`
+    pass a query, under the profiler span ``report.nrmse`` with the args
+    ``rows`` (the (query, site, stream) rows computed) and ``masked_rows``
+    (those with a non-finite entry, which took the per-row ``Q.nrmse``).
     """
     from repro.streaming.events import freshness_percentiles
-    E = topology.n_sites
     reg_idx = topology.region_of()
     bytes_per_site = np.asarray(bytes_per_site)
     cost_per_site = np.asarray(cost_per_site, np.float64)
 
     nrmse_site = {}                         # {q: (E, k)}
-    nrmse_site_q = {}
-    for q in qnames:
-        e_arr = est[q].transpose(1, 2, 0)   # (E, k, T)
-        eq_arr = est_q[q].transpose(1, 2, 0)
-        t_arr = tru[q].transpose(1, 2, 0)
-        nrmse_site[q] = np.asarray(
-            [Q.nrmse_table(e_arr[s], t_arr[s]) for s in range(E)])
-        nrmse_site_q[q] = np.asarray(
-            [Q.nrmse_table(eq_arr[s], t_arr[s]) for s in range(E)])
+    tables = [(est, nrmse_site)]
+    if est_q is est:            # the scan runtime: one table serves both
+        nrmse_site_q = nrmse_site
+    else:
+        nrmse_site_q = {}
+        tables.append((est_q, nrmse_site_q))
+    with jax.profiler.TraceAnnotation("report.nrmse") as span:
+        rows = masked = 0
+        for q in qnames:
+            t_arr = tru[q].transpose(1, 2, 0)   # (E, k, T)
+            for src, dst in tables:
+                dst[q], fast = Q.nrmse_rows(src[q].transpose(1, 2, 0), t_arr)
+                rows += fast.size
+                masked += fast.size - int(fast.sum())
+        span.set_metadata(rows=rows, masked_rows=masked)
 
     region_nrmse = {name: {} for name in topology.region_names}
     for r, name in enumerate(topology.region_names):

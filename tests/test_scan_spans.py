@@ -16,6 +16,7 @@ from jax.profiler import ProfileData
 from repro.adaptive import AdaptiveSpec
 from repro.api import (ControllerSpec, DataSpec, Experiment, ScenarioConfig,
                        TopologySpec)
+from repro.chaos import ChaosSpec
 from repro.core.types import PlannerConfig
 
 E, K, N = 8, 3, 32
@@ -25,7 +26,8 @@ STAGES = {"step.budgets", "step.plan", "step.sample", "step.impute",
           "step.queries", "step.truth", "step.update"}
 
 
-def _runtime(mode="rebalance", adaptive=None, collect="estimates"):
+def _runtime(mode="rebalance", adaptive=None, collect="estimates",
+             chaos=None, budget_fraction=0.25):
     scenario = ScenarioConfig(
         name="spans",
         data=DataSpec(dataset="fleet", n_points=2 * N, window=N, seed=1,
@@ -35,7 +37,7 @@ def _runtime(mode="rebalance", adaptive=None, collect="estimates"):
                               latency_scale=0.0),
         controller=ControllerSpec(mode=mode),
         queries=("AVG", "VAR", "MIN", "MAX"), adaptive=adaptive,
-        runtime="scan")
+        chaos=chaos, budget_fraction=budget_fraction, runtime="scan")
     rt = Experiment.from_scenario(scenario).runtime
     rt.collect = collect
     return rt
@@ -46,13 +48,13 @@ def _windows(T):
     return [rng.normal(size=(E, K, N)).astype(np.float32) for _ in range(T)]
 
 
-def _spans(log_dir):
+def _spans(log_dir, prefixes=("scan.",)):
     (path,) = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
                         recursive=True)
     return sorted(((ev.name, ev.start_ns, ev.end_ns, dict(ev.stats))
                    for plane in ProfileData.from_file(path).planes
                    for line in plane.lines for ev in line.events
-                   if ev.name.startswith("scan.")), key=lambda s: s[1])
+                   if ev.name.startswith(prefixes)), key=lambda s: s[1])
 
 
 @pytest.mark.parametrize("collect", ["estimates", "payloads"])
@@ -72,6 +74,33 @@ def test_run_writes_five_phase_spans_a_call(tmp_path, collect):
     assert res["scan_seconds"] * 1e9 <= spans[2][2] - spans[2][1]
     assert res["windows_per_sec"] == 2 / res["scan_seconds"]
     assert not hasattr(rt, "plan_seconds")
+
+
+@pytest.mark.parametrize("chaos", [
+    None, ChaosSpec(flaps=((0, 1, "down"),))])
+def test_report_writes_one_nrmse_span_inside_scan_report(tmp_path, chaos):
+    # a budget of one window's tuples: every stream draws enough samples
+    # for a VAR answer, so only the down site leaves non-finite rows
+    rt = _runtime(chaos=chaos, budget_fraction=1.0)
+    tables = []
+    report_fn = rt._result_fleet
+
+    def keep(est, tru, *a, **kw):
+        tables.append((est, tru))
+        return report_fn(est, tru, *a, **kw)
+    rt._result_fleet = keep
+    w = _windows(2)
+    first = rt.run(w, n_windows=2)
+    with jax.profiler.trace(str(tmp_path)):
+        rt.run(w, n_windows=2, state=first["final_state"])
+    report, nrmse = _spans(tmp_path, ("scan.report", "report."))
+    assert (report[0], nrmse[0]) == ("scan.report", "report.nrmse")
+    assert report[1] <= nrmse[1] <= nrmse[2] <= report[2]
+    est, tru = tables[-1]
+    masked = sum(int((~np.isfinite(est[q]) | ~np.isfinite(tru[q]))
+                     .any(axis=0).sum()) for q in tru)
+    assert (masked > 0) == (chaos is not None)
+    assert nrmse[3] == {"rows": 4 * E * K, "masked_rows": masked}
 
 
 @pytest.mark.parametrize("mode,adaptive,stages", [
