@@ -41,6 +41,7 @@ from repro.adaptive import drift as drift_mod
 from repro.adaptive import stats as ew_mod
 from repro.api.registry import DRIFT_DETECTORS
 from repro.core.types import Array
+from repro.parallel.sharding import exchange_pmax
 
 
 @dataclasses.dataclass(frozen=True)
@@ -137,7 +138,7 @@ def gate_update(spec: AdaptiveSpec, gate: GateState, values: Array,
         # replicated detector scalar downstream) is bitwise the
         # single-device gate's; padded sites (zero values, zero assumed
         # corr) contribute dev = 0.
-        dev = jax.lax.pmax(dev, axis_name)
+        dev = exchange_pmax(dev, axis_name)
 
     det_state, fire, lag = drift_mod.detector_update(
         spec.detector, {"accum": gate.det_accum, "age": gate.det_age},

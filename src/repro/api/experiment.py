@@ -32,6 +32,7 @@ from repro.core import queries as Q
 from repro.core.reconstruct import reconstruct_window
 from repro.core.types import EdgePayload, PlannerConfig, WindowBatch
 from repro.api.scenario import ControllerSpec, ScenarioConfig
+from repro.parallel.sharding import EXCHANGE_COUNTERS
 
 
 # ==========================================================================
@@ -509,6 +510,11 @@ class RunReport:
     availability_by_region: Optional[dict] = None
     outage_nrmse: Optional[dict] = None
     steady_nrmse: Optional[dict] = None
+    # scan runtimes: the window step's cross-device collectives a window
+    # (0 on one device); None = a runtime that does not count them
+    exchange_all_gathers: Optional[int] = None
+    exchange_all_reduces: Optional[int] = None
+    exchange_gather_bytes: Optional[int] = None
 
     @property
     def wan_fraction(self) -> float:
@@ -548,6 +554,9 @@ class RunReport:
             d["availability_by_region"] = dict(self.availability_by_region)
             d["outage_nrmse"] = dict(self.outage_nrmse)
             d["steady_nrmse"] = dict(self.steady_nrmse)
+        for f in EXCHANGE_COUNTERS:
+            if getattr(self, f) is not None:
+                d[f] = getattr(self, f)
         return d
 
     def summary(self) -> str:
@@ -576,7 +585,7 @@ def _report_single(scenario, r: dict) -> RunReport:
         freshness_ms=dict(r["freshness_ms"]),
         freshness_by_region={"local": dict(r["freshness_ms"])},
         plan_seconds=float(r["plan_seconds"]),
-        raw=r)
+        raw=r, **_exchange_counters(r))
 
 
 def _report_fleet(scenario, r: dict, n_sites: int) -> RunReport:
@@ -615,7 +624,12 @@ def _report_fleet(scenario, r: dict, n_sites: int) -> RunReport:
         outage_nrmse=(dict(r["outage_nrmse"])
                       if "outage_nrmse" in r else None),
         steady_nrmse=(dict(r["steady_nrmse"])
-                      if "steady_nrmse" in r else None))
+                      if "steady_nrmse" in r else None),
+        **_exchange_counters(r))
+
+
+def _exchange_counters(r: dict) -> dict:
+    return {f: int(r[f]) for f in EXCHANGE_COUNTERS if f in r}
 
 
 # ==========================================================================

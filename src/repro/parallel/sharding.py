@@ -57,6 +57,55 @@ def pad_site_axis(x, n_padded: int, fill=0):
     pad = jnp.full((int(n_padded) - e,) + tuple(x.shape[1:]), fill, x.dtype)
     return jnp.concatenate([x, pad])
 
+
+# ---------------------------------------------------------------------------
+# the site mesh's cross-device exchange
+# ---------------------------------------------------------------------------
+
+EXCHANGE_SCOPE = "exchange"
+EXCHANGE_COUNTERS = ("exchange_all_gathers", "exchange_all_reduces",
+                     "exchange_gather_bytes")
+
+
+def _tally(counter: str, n: int) -> None:
+    counts = getattr(_state, "exchange", None)
+    if counts is not None:
+        counts[counter] += int(n)
+
+
+@contextlib.contextmanager
+def count_exchange():
+    """Count the site-mesh collectives traced while open: yields
+    ``{counter: n}`` over :data:`EXCHANGE_COUNTERS`, the all-gathers, the
+    all-reduces and the bytes each device receives from its all-gathers.
+    Traced once per window step, they are the step's counts a window."""
+    prev = getattr(_state, "exchange", None)
+    _state.exchange = counts = dict.fromkeys(EXCHANGE_COUNTERS, 0)
+    try:
+        yield counts
+    finally:
+        _state.exchange = prev
+
+
+def exchange_all_gather(x, axis_name: str):
+    """``all_gather(tiled=True)`` over the site mesh, under the
+    ``exchange`` scope (op metadata only) and counted."""
+    with jax.named_scope(EXCHANGE_SCOPE):
+        out = jax.lax.all_gather(x, axis_name, tiled=True)
+    _tally("exchange_all_gathers", 1)
+    _tally("exchange_gather_bytes", out.size * out.dtype.itemsize)
+    return out
+
+
+def exchange_pmax(x, axis_name: str):
+    """``pmax`` over the site mesh, under the ``exchange`` scope and
+    counted."""
+    with jax.named_scope(EXCHANGE_SCOPE):
+        out = jax.lax.pmax(x, axis_name)
+    _tally("exchange_all_reduces", 1)
+    return out
+
+
 # logical activation axis -> mesh axes (None = replicated)
 ACTIVATION_RULES = {
     "batch": ("pod", "data"),

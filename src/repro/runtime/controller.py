@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.parallel.sharding import exchange_all_gather, exchange_pmax
 from repro.runtime.state import ControllerState
 
 
@@ -88,7 +89,9 @@ def water_fill(demand, total: float, lo, hi, iters: int = 8,
     sums it in the single-device program's order, so sharded budgets are
     bitwise those of one device.  (A ``psum`` of per-device partial sums
     re-associates the fleet sum; on four TPU chips that moved budgets by
-    an ULP and flipped ``floor(budget)`` for some sites.)
+    an ULP and flipped ``floor(budget)`` for some sites.)  The
+    collectives run under the ``exchange`` scope and are counted
+    (:func:`~repro.parallel.sharding.count_exchange`).
     """
     if axis_name is None:
         gsum = ordered_sum
@@ -96,11 +99,11 @@ def water_fill(demand, total: float, lo, hi, iters: int = 8,
             return jnp.any(x)
     else:
         def gsum(x):
-            return ordered_sum(
-                jax.lax.all_gather(x, axis_name, tiled=True))
+            return ordered_sum(exchange_all_gather(x, axis_name))
 
         def gany(x):
-            return jax.lax.pmax(jnp.any(x).astype(jnp.int32), axis_name) > 0
+            return exchange_pmax(jnp.any(x).astype(jnp.int32),
+                                 axis_name) > 0
     d = jnp.where(jnp.isfinite(demand), demand, 0.0)
     # no usable signal (all zero/non-finite, e.g. every site dark):
     # uniform in the box instead of NaN-poisoning the carry

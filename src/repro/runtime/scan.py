@@ -51,10 +51,18 @@ import numpy as np
 
 from repro.api.registry import ENGINES, MODELS
 from repro.core import queries as Q
+from repro.parallel.sharding import EXCHANGE_COUNTERS
 from repro.runtime.controller import CtrlParams
 from repro.runtime.state import init_state
 from repro.runtime.step import (PAYLOAD_PLAN_FIELDS, SCAN_QUERIES,
                                 make_window_step)
+
+
+def placed_bytes(tree) -> int:
+    """Bytes a pytree of placed arrays holds on its devices, summed over
+    every device's shard (a replicated array counts once per device)."""
+    return sum(s.data.nbytes for x in jax.tree.leaves(tree)
+               for s in x.addressable_shards)
 
 
 class _Prepared(NamedTuple):
@@ -148,6 +156,9 @@ class ScanRuntime:
         # runtime overrides it with E padded to the device multiple
         self._run_sites = self.n_sites
         self._calls = 0                # run calls so far: the spans' `call`
+        # the window step's cross-device collectives a window: none on one
+        # device; the sharded runtime counts its own as it traces the step
+        self._exchange = dict.fromkeys(EXCHANGE_COUNTERS, 0)
 
     @classmethod
     def from_scenario(cls, scenario, *, use_kernel=None, interpret=False,
@@ -345,6 +356,10 @@ class ScanRuntime:
         wait), ``scan.readback`` and ``scan.report``, each with the args
         ``call`` (this runtime's count of ``run`` calls) and ``windows``
         (T); see docs/runtime.md, "Tracing a serving process".
+        ``scan.place`` also carries ``devices`` (the devices the arguments
+        went to) and ``bytes`` (what they hold there, replicas counted on
+        every device).  The result's ``exchange_*`` counters are the window
+        step's cross-device collectives a window (0 on one device).
         """
         single = self.topology is None
         self._calls += 1
@@ -357,8 +372,11 @@ class ScanRuntime:
         with span("scan.prepare"):
             fn, state, xs, pool_np, live_tbl, k, n, T, w0 = \
                 self._prepare(windows, n_windows, state, first_window)
-        with span("scan.place"):
+        with span("scan.place") as place:
             state, xs, pool = self._place(state, xs, pool_np)
+            if place.is_enabled():
+                place.set_metadata(devices=len(pool.sharding.device_set),
+                                   bytes=placed_bytes((state, xs, pool)))
 
         with span("scan.execute"):
             t0 = time.perf_counter()
@@ -405,6 +423,7 @@ class ScanRuntime:
             "plan_raw": {f: ys[f] for f in
                          ("budgets", "obs_err", "r2", "objective")},
             "bytes_history": ys["bytes"],
+            **self._exchange,
         }
         with span("scan.report"):
             if single:

@@ -28,7 +28,10 @@ Collective inventory (per window, rebalance controller only)
     genuinely fleet-global computation); every device sums the gathered
     vector in the single-device order
     (:func:`~repro.runtime.controller.ordered_sum`).  Adaptive runs add
-    one ``pmax`` for the drift gate's deviation max.
+    one ``pmax`` for the drift gate's deviation max.  Every collective
+    runs under the ``exchange`` scope, and the result dict counts them a
+    window (``exchange_all_gathers``, ``exchange_all_reduces``,
+    ``exchange_gather_bytes``: each device's gathered bytes).
     Static-budget runs are collective-free: the whole window step is then
     embarrassingly parallel, like the sharded plan engine.
 
@@ -60,7 +63,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.parallel.sharding import site_mesh, site_pad
+from repro.parallel.sharding import count_exchange, site_mesh, site_pad
 from repro.runtime.scan import ScanRuntime
 from repro.runtime.step import make_window_step
 
@@ -150,7 +153,16 @@ class ShardedScanRuntime(ScanRuntime):
                     adaptive=self.adaptive, use_kernel=self.use_kernel,
                     interpret=self.interpret, chaos=True, axis_name=AXIS,
                     sample_slice=(e, e_pad, offset))
-                return jax.lax.scan(step, state, xs)
+
+                def counted(state, x):
+                    # the collectives of one traced window step are the
+                    # run's exchange a window
+                    with count_exchange() as counts:
+                        out = step(state, x)
+                    self._exchange = counts
+                    return out
+
+                return jax.lax.scan(counted, state, xs)
 
             def fn(state, xs, pool):
                 specs = self._state_specs(state)

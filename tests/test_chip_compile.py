@@ -124,3 +124,107 @@ def test_sharded_scan_compiles_on_four_chips(topo):
     all_gathers = re.findall(r"\ball-gather(?:-start)?\(", text)
     assert len(all_reduces) == 1
     assert len(all_gathers) == 1 + 2 * iters
+
+
+# ------------------------------------- the benchmark's programs, full size
+
+def _bench():
+    """The benchmark harness and the exchange reader, from ``bench/``."""
+    import importlib.util
+    import sys
+    bench = os.path.join(os.path.dirname(__file__), "..", "bench")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    import run as harness
+    spec = importlib.util.spec_from_file_location(
+        "exchange_ms", os.path.join(bench, "metrics", "exchange_ms.py"))
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    return harness, reader
+
+
+def _cell_runtime(harness, config, chips):
+    """A cell's configuration and its served runtime as ``bench/run.py``
+    builds it, with the kernels a chip runs."""
+    cfg = harness.generate.load("configs", config)
+    rt = harness.build_runtime(cfg, chips)
+    rt.use_kernel, rt.interpret = True, False
+    shape = (int(cfg["sites"]), int(cfg["streams_per_site"]),
+             int(cfg["window"]))
+    per_call = harness.generate.load("traffic", "bulk")["windows_per_call"]
+    return rt, shape, int(per_call)
+
+
+def _collectives(text, reader):
+    """{instruction: opcode} of the compiled module's collectives."""
+    from tracefile import Op
+    out = {}
+    for line in text.splitlines():
+        m = re.match(r"^\s*(?:ROOT )?%([\w.\-]+) = (.*)$", line)
+        if m:
+            kind = Op(f"%{m.group(1)} = {m.group(2)}", 0, 0).kind
+            if reader.is_collective(kind):
+                out[m.group(1)] = kind
+    return out
+
+
+def test_pems_bulk4_program_compiles_with_every_collective_in_the_exchange(
+        topo):
+    """``pems_ca.bulk4`` at full size (E=8600, k=3, N=288, 16 windows a
+    call) over four chips: 2,150 sites a chip, a shard the compiler cannot
+    tile, so each of the water-fill's 17 all-gathers becomes a
+    dynamic-update-slice and an all-reduce without metadata; with the
+    pmax, 18 all-reduces a window, all of them the exchange's."""
+    harness, reader = _bench()
+    from repro.runtime.controller import water_fill
+    rt, (e, k, n), t = _cell_runtime(harness, "pems_ca", 4)
+    mesh = Mesh(np.asarray(topo.devices), ("sites",))
+    rt._mesh = mesh
+    state = init_state(e, k, rt.ctrl.equal_share)
+    state = dataclasses.replace(
+        state, chaos=make_chaos_carry(e, k, rt.query_names))
+    state = jax.tree.map(
+        lambda x, s: _spec(np.shape(x), np.asarray(x).dtype,
+                           NamedSharding(mesh, s)),
+        state, rt._state_specs(state))
+    xs = (_spec((t,), jnp.int32, NamedSharding(mesh, P())),
+          _spec((t, e), bool, NamedSharding(mesh, P(None, "sites"))))
+    pool = _spec((t, e, k, n), jnp.float32,
+                 NamedSharding(mesh, P(None, "sites")))
+    text = rt._scan_fn(None).lower(state, xs, pool).compile().as_text()
+    assert chip_smoke.kernel_calls(text)["total"] == 2
+    iters = inspect.signature(water_fill).parameters["iters"].default
+    assert rt._exchange == {"exchange_all_gathers": 1 + 2 * iters,
+                            "exchange_all_reduces": 1,
+                            "exchange_gather_bytes": (1 + 2 * iters) * 4 * e}
+    coll = _collectives(text, reader)
+    assert sorted(coll.values()) == ["all-reduce"] * (2 + 2 * iters)
+    exchange = reader.exchange_instructions(text)
+    assert set(coll) <= exchange
+    # the scope nests inside step.budgets: the stage split keeps its meaning
+    import scopes
+    stages = scopes.hlo_scopes(text)
+    assert {stages[x] for x in exchange if x in coll} <= {"step.budgets",
+                                                         None}
+    assert "step.budgets" in {stages[x] for x in coll}
+
+
+def test_city_bulk_program_keeps_its_stages_and_has_no_exchange(one_chip):
+    """``city.bulk`` on one chip: ``bench/scopes.hlo_scopes`` finds the
+    window step's seven stages and nothing else, and the program holds no
+    collective, so nothing of it lies under ``exchange``."""
+    harness, reader = _bench()
+    import scopes
+    rt, (e, k, n), t = _cell_runtime(harness, "city", 1)
+    state = jax.tree.map(
+        lambda x: _spec(np.shape(x), np.asarray(x).dtype, one_chip),
+        init_state(e, k, rt.ctrl.equal_share))
+    text = rt._scan_fn(None).lower(
+        state, _spec((t,), jnp.int32, one_chip),
+        _spec((t, e, k, n), jnp.float32, one_chip)).compile().as_text()
+    assert set(scopes.hlo_scopes(text).values()) == {
+        "step.budgets", "step.plan", "step.sample", "step.impute",
+        "step.queries", "step.truth", "step.update", None}
+    assert _collectives(text, reader) == {}
+    assert reader.exchange_instructions(text) == set()
+    assert rt._exchange == dict.fromkeys(rt._exchange, 0)

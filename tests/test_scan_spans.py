@@ -69,8 +69,11 @@ def test_run_writes_five_phase_spans_a_call(tmp_path, collect):
     assert [s[0] for s in spans] == PHASES * 2
     assert all(a[2] <= b[1] for a, b in zip(spans, spans[1:]))
     assert all(s[1] <= s[2] for s in spans)
-    assert [s[3] for s in spans] == (
+    assert [{a: s[3][a] for a in ("call", "windows")} for s in spans] == (
         [{"call": 2, "windows": 2}] * 5 + [{"call": 3, "windows": 1}] * 5)
+    # scan.place adds its devices and bytes; the other four carry no more
+    assert [set(s[3]) - {"call", "windows"} for s in spans] == (
+        [set(), {"devices", "bytes"}, set(), set(), set()] * 2)
     assert res["scan_seconds"] * 1e9 <= spans[2][2] - spans[2][1]
     assert res["windows_per_sec"] == 2 / res["scan_seconds"]
     assert not hasattr(rt, "plan_seconds")
@@ -113,3 +116,23 @@ def test_the_compiled_step_names_every_stage(mode, adaptive, stages):
     text = rt.lower(_windows(2), 2).compile().as_text()
     named = set(re.findall(r'op_name="[^"]*?(step\.[a-z]+)/', text))
     assert named == stages
+
+
+def test_place_span_carries_its_devices_and_bytes(tmp_path):
+    """``scan.place`` names the devices the call's arguments went to and
+    the bytes they hold there: the pool, the carry and the window ids."""
+    rt = _runtime()
+    w = _windows(2)
+    first = rt.run(w, n_windows=2)
+    carry = first["final_state"]
+    with jax.profiler.trace(str(tmp_path)):
+        rt.run(w, n_windows=2, state=carry)
+    (place,) = [s for s in _spans(tmp_path) if s[0] == "scan.place"]
+    pool = np.stack(w)
+    carry_bytes = sum(np.asarray(x).nbytes for x in jax.tree.leaves(carry))
+    wids = np.arange(2, dtype=np.int32)
+    assert place[3] == {"call": 2, "windows": 2, "devices": 1,
+                        "bytes": pool.nbytes + carry_bytes + wids.nbytes}
+    assert first["exchange_all_gathers"] == 0
+    assert first["exchange_all_reduces"] == 0
+    assert first["exchange_gather_bytes"] == 0
