@@ -1,7 +1,7 @@
 """ShardedScanRuntime — the whole per-window cycle on the site mesh.
 
 :class:`~repro.runtime.scan.ScanRuntime` keeps the full window step —
-controller budgets → Algorithm-1 plan → Fisher-Yates sampling → imputation
+controller budgets → Algorithm-1 plan → SRS sampling → imputation
 → queries → controller update — inside one ``lax.scan``, but on a single
 device; only the *planning* stage could shard (PR 5's engine).  This
 runtime wraps the scan itself in ``shard_map`` over the 1-D ``("sites",)``

@@ -14,9 +14,10 @@ RNG parity (bit-for-bit with the event-loop paths):
     subkeys walk the same sequential ``jax.random.split`` chain and stream
     ``i`` draws ``perm = permutation(sub, N)[:n_i]`` — the identical index
     sequence, so single-edge scan runs agree with the host planner bitwise.
-  * E > 1 — one batched Fisher-Yates shuffle per window keyed on
-    ``fold_in(PRNGKey(seed ^ wid), 0x5A)`` (O(N) per row; sort-based
-    shuffles serialize on XLA:CPU).  The fleet runtime's
+  * E > 1 — one keyed sort per window: a random u32 key per (site,
+    stream, position) from ``fold_in(PRNGKey(seed ^ wid), 0x5A)``, and a
+    stable sort along the window axis that carries the values, so each
+    row's front is its sample.  The fleet runtime's
     ``sampling="device"`` mode draws through the same function
     (:func:`draw_fleet_samples`, one jitted call per window), so the event
     loop and the scan consume identical sample sets by construction
@@ -67,55 +68,36 @@ def _site_keys(seed: int, wid, n_sites: int):
         jnp.arange(n_sites, dtype=jnp.int32))
 
 
-def _fy_sample(key, values, n_real, sample_slice=None):
-    """Batched partial Fisher-Yates SRS for every (site, stream) row.
+def _keyed_sample(key, values, n_real, sample_slice=None):
+    """SRS without replacement for every (site, stream) row by one keyed sort.
 
-    One uniform draw per position up front, then fori_loop steps of
-    (E, k)-wide gather/scatter swaps on a compact u8/u16 index permutation
-    — O(N) work per row where a sort (or the O(N^2) counting-rank form)
-    serializes the whole window step on a single-core XLA:CPU host.  FY
-    position ``i`` is final after its own iteration and the caller masks
-    everything past ``n_real``, so the loop stops at ``max(n_real)`` —
-    identical output, typically far fewer than N iterations.
+    One random u32 key per (site, stream, position); a stable sort along
+    the window axis carries the values as its payload, so each row comes
+    out in a uniformly random order (ties, ~N^2/2^33 a row, keep position
+    order) and its first ``n_real`` entries are the sample.  No index
+    permutation is built and nothing is gathered.
 
     ``sample_slice`` = ``(e_rng, e_pad, offset)`` (sharded scan runtime):
     ``values`` is the local shard of a fleet padded to ``e_pad`` sites, of
     which the first ``e_rng`` are real.  Threefry draws are NOT prefix-
-    stable across shapes, so every device draws the uniform tensor at the
-    *global unpadded* shape ``(e_rng, k, n)`` — the exact tensor the
-    batched scan draws — zero-pads it to ``e_pad`` rows and slices its own
-    rows at ``offset``.  Real rows therefore consume bitwise the batched
-    run's uniforms (replicated RNG generation is the price of parity);
-    padded rows see u = 0, i.e. identity swaps, and are masked to zero by
-    ``n_real = 0`` anyway.
+    stable across shapes, so every device draws the keys at the *global
+    unpadded* shape ``(e_rng, k, n)`` — the exact tensor the batched scan
+    draws — zero-pads it to ``e_pad`` rows and slices its own rows at
+    ``offset``.  Real rows therefore sort by bitwise the batched run's
+    keys; padded rows are masked to zero by ``n_real = 0``.
     """
     e, k, n = values.shape
-    idx_dtype = jnp.uint8 if n <= 256 else jnp.uint16
     if sample_slice is None:
-        u = jax.random.uniform(key, (e, k, n))
+        keys = jax.random.bits(key, (e, k, n), jnp.uint32)
     else:
         e_rng, e_pad, offset = sample_slice
-        u_full = jax.random.uniform(key, (e_rng, k, n))
+        keys = jax.random.bits(key, (e_rng, k, n), jnp.uint32)
         if e_pad > e_rng:
-            u_full = jnp.concatenate(
-                [u_full, jnp.zeros((e_pad - e_rng, k, n), u_full.dtype)])
-        u = jax.lax.dynamic_slice_in_dim(u_full, offset, e, axis=0)
-    ei = jnp.arange(e)[:, None]
-    ki = jnp.arange(k)[None, :]
-    perm0 = jnp.broadcast_to(jnp.arange(n, dtype=idx_dtype), (e, k, n))
-
-    def body(i, perm):
-        # swap position i with uniform j in [i, n)
-        j = i + (u[..., i] * (n - i)).astype(jnp.int32)
-        j = jnp.minimum(j, n - 1)
-        pi = perm[..., i]
-        pj = jnp.take_along_axis(perm, j[..., None], axis=-1)[..., 0]
-        perm = perm.at[ei, ki, j].set(pi)
-        return perm.at[..., i].set(pj)
-
-    stop = jnp.minimum(jnp.max(n_real).astype(jnp.int32), n - 1)
-    perm = jax.lax.fori_loop(0, stop, body, perm0)
-    shuffled = jnp.take_along_axis(values, perm.astype(jnp.int32), axis=-1)
+            keys = jnp.concatenate(
+                [keys, jnp.zeros((e_pad - e_rng, k, n), keys.dtype)])
+        keys = jax.lax.dynamic_slice_in_dim(keys, offset, e, axis=0)
+    _, shuffled = jax.lax.sort((keys, values), dimension=-1, is_stable=True,
+                               num_keys=1)
     return jnp.where(jnp.arange(n)[None, None, :] < n_real[..., None],
                      shuffled, 0.0)
 
@@ -130,9 +112,9 @@ def sample_fleet(seed: int, wid, values, n_real, sample_slice=None):
 
     E == 1 replicates the host planner's sampler exactly (the sequential
     ``draw_samples`` split chain and ``jax.random.permutation``), keeping
-    single-edge scan runs bitwise against ``plan_one``.  Fleets use the
-    O(N)-per-row Fisher-Yates shuffle instead — both the scan and the
-    event loop's ``sampling="device"`` mode draw through this same
+    single-edge scan runs bitwise against ``plan_one``.  Fleets sort every
+    row by random keys instead (:func:`_keyed_sample`) — both the scan and
+    the event loop's ``sampling="device"`` mode draw through this same
     function, so scan/event parity is preserved by construction.
     """
     e, k, n = values.shape
@@ -149,8 +131,8 @@ def sample_fleet(seed: int, wid, values, n_real, sample_slice=None):
     base = jax.random.PRNGKey(
         jnp.bitwise_xor(jnp.asarray(seed, jnp.int32),
                         jnp.asarray(wid, jnp.int32)))
-    return _fy_sample(jax.random.fold_in(base, 0x5A), values, n_real,
-                      sample_slice=sample_slice)
+    return _keyed_sample(jax.random.fold_in(base, 0x5A), values, n_real,
+                         sample_slice=sample_slice)
 
 
 @functools.lru_cache(maxsize=8)
@@ -295,8 +277,8 @@ def make_window_step(pool, *, seed: int, plan_fn, qnames, multi: bool,
     single-device order) and the adaptive
     gate's deviation max (pmax) — across the mesh; everything else in the
     step is per-site and stays collective-free.  ``sample_slice``
-    ``(e_rng, e_pad, offset)`` makes the Fisher-Yates draw consume the
-    batched run's exact global uniforms (see :func:`_fy_sample`).  Both
+    ``(e_rng, e_pad, offset)`` makes the sampler sort by the batched
+    run's exact global keys (see :func:`_keyed_sample`).  Both
     default to None, which traces the unchanged single-device graph.
 
     Each stage runs under a ``jax.named_scope`` (``step.budgets``,
@@ -363,8 +345,8 @@ def make_window_step(pool, *, seed: int, plan_fn, qnames, multi: bool,
         if live is not None:
             # closed_form_alloc floors every stream at 1 sample even on a
             # zero budget; dead sites must truly ship nothing.  Masking
-            # n_real leaves live rows' FY draws bitwise intact (the
-            # shuffle's stop = max(n_real) still covers every live row).
+            # n_real leaves live rows' samples bitwise intact (every row
+            # is sorted whatever its n_real).
             with jax.named_scope("step.plan"):
                 plan = dataclasses.replace(
                     plan, n_real=plan.n_real * live[:, None].astype(

@@ -126,6 +126,24 @@ def test_sharded_scan_compiles_on_four_chips(topo):
     assert len(all_gathers) == 1 + 2 * iters
 
 
+def test_fleet_sampler_is_one_sort_without_loop_or_gather(one_chip):
+    """``sample_fleet`` at ``city``'s (449, 5, 288) compiles to one sort
+    along the window axis: no ``while`` loop, and no gather whose output
+    holds all E*k*N values."""
+    from repro.runtime.step import sample_fleet
+    e, k, n = 449, 5, 288
+    text = jax.jit(sample_fleet, static_argnums=0).lower(
+        7, _spec((), jnp.int32, one_chip),
+        _spec((e, k, n), jnp.float32, one_chip),
+        _spec((e, k), jnp.int32, one_chip)).compile().as_text()
+    sorts = re.findall(r"\bsort\((.*)", text)
+    assert len(sorts) == 1 and "dimensions={2}" in sorts[0]
+    assert not re.search(r"\bwhile\(", text)
+    gathered = [np.prod([int(d) for d in dims.split(",") if d]) for dims in
+                re.findall(r"= \w+\[([\d,]*)\]\S* gather\(", text)]
+    assert e * k * n not in gathered, gathered
+
+
 # ------------------------------------- the benchmark's programs, full size
 
 def _bench():

@@ -202,24 +202,64 @@ def test_sample_fleet_e1_matches_host_draw_samples():
         assert not dev[0, i, n_real[0, i]:].any()
 
 
-def test_fleet_sampler_is_deterministic_srs():
-    """E>1 Fisher-Yates path: SRS without replacement per (site, stream),
-    deterministic in (seed, wid), zero past n_real."""
+@pytest.mark.parametrize("E,k,n", [(5, K, 17), (4, 3, 288), (4, 5, 288),
+                                   (3, 2, 300)])
+def test_fleet_sampler_is_deterministic_srs(E, k, n):
+    """E>1 keyed-sort path: SRS without replacement per (site, stream),
+    deterministic in (seed, wid), zero past n_real; at the cells' N=288
+    and past 256."""
     rng = np.random.default_rng(1)
-    E, n = 5, 17
-    values = rng.permutation(E * K * n).reshape(E, K, n).astype(np.float32)
-    n_real = rng.integers(0, n + 1, size=(E, K)).astype(np.int32)
+    values = (rng.permutation(E * k * n).reshape(E, k, n) + 1).astype(
+        np.float32)
+    n_real = rng.integers(0, n + 1, size=(E, k)).astype(np.int32)
+    n_real[0, 0] = n
 
     out = draw_fleet_samples(9, 2, values, n_real)
     np.testing.assert_array_equal(out, draw_fleet_samples(9, 2, values,
                                                           n_real))
     assert not np.array_equal(out, draw_fleet_samples(9, 3, values, n_real))
     for s in range(E):
-        for i in range(K):
+        for i in range(k):
             prefix = out[s, i, :n_real[s, i]]
             assert len(np.unique(prefix)) == n_real[s, i]   # no replacement
             assert np.isin(prefix, values[s, i]).all()      # from the row
             assert not out[s, i, n_real[s, i]:].any()
+
+
+def _chi2_p(observed, expected, scale=1.0):
+    """Survival of the pooled Pearson statistic (divided by ``scale``) on
+    ``observed.size - rows`` degrees of freedom; one row per leading
+    index."""
+    from scipy.stats import chi2
+    obs = np.asarray(observed, np.float64).reshape(-1, observed.shape[-1])
+    exp = np.asarray(expected, np.float64).reshape(-1, 1)
+    stat = (((obs - exp) ** 2 / exp).sum(-1) / np.ravel(scale)).sum()
+    return chi2.sf(stat, obs.size - obs.shape[0])
+
+
+@pytest.mark.parametrize("what", ["inclusion", "first"])
+def test_fleet_sampler_is_uniform(what):
+    """Over 2,000 window ids at E=4, k=2, N=12, every position is sampled
+    equally often and comes first equally often (chi-square, p > 1e-3)."""
+    E, k, n, T = 4, 2, 12, 2000
+    values = np.broadcast_to(np.arange(1, n + 1, dtype=np.float32),
+                             (E, k, n))
+    n_real = np.array([[1, 2], [3, 5], [6, 8], [9, 11]], np.int32)
+    draw = jax.jit(jax.vmap(lambda w: sample_fleet(
+        11, w, jnp.asarray(values), jnp.asarray(n_real))))
+    out = np.asarray(draw(jnp.arange(T, dtype=jnp.int32)))    # (T, E, k, n)
+    pos = out.astype(np.int64) - 1                           # -1 = unsampled
+    if what == "inclusion":
+        counts = np.stack([(pos == j).sum(0).sum(-1) for j in range(n)], -1)
+        np.testing.assert_array_equal(counts.sum(-1), T * n_real)
+        # inclusion counts of an n_real-of-n SRS: the Pearson statistic is
+        # (n - n_real)/(n - 1) times a chi-square on n - 1 freedoms
+        p = _chi2_p(counts, T * n_real / n, (n - n_real) / (n - 1))
+    else:
+        first = pos[..., 0]
+        counts = np.stack([(first == j).sum(0) for j in range(n)], -1)
+        p = _chi2_p(counts, np.full((E, k), T / n))
+    assert p > 1e-3, p
 
 
 def test_ordinal_ranks_matches_stable_double_argsort():

@@ -82,9 +82,10 @@ def test_run_writes_five_phase_spans_a_call(tmp_path, collect):
 @pytest.mark.parametrize("chaos", [
     None, ChaosSpec(flaps=((0, 1, "down"),))])
 def test_report_writes_one_nrmse_span_inside_scan_report(tmp_path, chaos):
-    # a budget of one window's tuples: every stream draws enough samples
-    # for a VAR answer, so only the down site leaves non-finite rows
-    rt = _runtime(chaos=chaos, budget_fraction=1.0)
+    # a static budget of one window's tuples: every stream draws enough
+    # samples for a VAR answer, so only the down site leaves non-finite
+    # rows (a rebalancing controller may starve a stream to one sample)
+    rt = _runtime(mode="static", chaos=chaos, budget_fraction=1.0)
     tables = []
     report_fn = rt._result_fleet
 
