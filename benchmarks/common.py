@@ -80,8 +80,9 @@ def fmt(v):
 # --------------------------------------------------------------------------
 # One stable, diffable schema so the perf trajectory is reviewable across
 # PRs.  ``rows`` is a flat list of per-configuration measurements; the
-# required keys below are the contract CI validates (scripts/ci.sh runs
-# ``throughput_bench.py --smoke`` which calls validate_bench_json).
+# required keys below are the contract ``validate_bench_json`` checks on
+# every write and read (``throughput_bench.py --smoke`` reads the committed
+# BENCH_throughput.json through it).
 
 BENCH_SCHEMA_VERSION = 1
 

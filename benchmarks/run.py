@@ -1,12 +1,12 @@
 """Benchmark harness — one module per paper figure/table.
 
-``python -m benchmarks.run [--only fig4,fig5] [--skip grad_exchange]``
+``python -m benchmarks.run [--only fig4,fig5] [--skip kernel_bench]``
 prints ``name,us_per_call,derived`` CSV rows.
 
 ``python -m benchmarks.run --smoke`` runs the compact Scenario-API smoke
 table instead (benchmarks.common.SMOKE_SCENARIOS): one small scenario per
-registered component family, through ``Experiment.from_scenario`` — the CI
-fast path.
+registered component family, through ``Experiment.from_scenario``
+(``tests/test_smoke_table.py`` runs the same table, one case a scenario).
 """
 from __future__ import annotations
 
@@ -19,7 +19,7 @@ MODULES = [
     "fig3_heuristic", "fig4_turbine", "fig5_smartcity", "fig6_latency",
     "fig7_bias", "fig8_correlation", "fig9_iid", "fig10_models",
     "fig11_costs", "fig12_multi_predictor", "kernel_bench",
-    "fleet_bench", "roofline_report", "grad_exchange", "throughput_bench",
+    "fleet_bench", "throughput_bench",
 ]
 
 

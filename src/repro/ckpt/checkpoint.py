@@ -9,10 +9,11 @@ never shadows the previous good checkpoint (restart-safe).
 
 Cross-mesh restore: leaves are loaded host-side and ``jax.device_put`` with
 the *target* mesh's shardings, so a checkpoint taken on one mesh restores
-onto a different one (elastic data-axis grow/shrink, single<->multi pod).
+onto a different one (the scan runtime's carry on one device <-> the
+sharded runtime's carry on a site mesh).
 
 Async: ``CheckpointManager(async_save=True)`` snapshots to host then writes
-on a worker thread, overlapping I/O with the next training steps.
+on a worker thread, overlapping I/O with the next windows.
 """
 from __future__ import annotations
 

@@ -1,8 +1,6 @@
-from repro.parallel.sharding import (ACTIVATION_RULES, batch_axes,
-                                     logical_sharding_constraint, mesh_context,
-                                     param_partition_spec, tree_pspecs,
-                                     tree_shardings)
+from repro.parallel.sharding import (EXCHANGE_COUNTERS, count_exchange,
+                                     exchange_all_gather, exchange_pmax,
+                                     pad_site_axis, site_mesh, site_pad)
 
-__all__ = ["ACTIVATION_RULES", "batch_axes", "logical_sharding_constraint",
-           "mesh_context", "param_partition_spec", "tree_pspecs",
-           "tree_shardings"]
+__all__ = ["EXCHANGE_COUNTERS", "count_exchange", "exchange_all_gather",
+           "exchange_pmax", "pad_site_axis", "site_mesh", "site_pad"]

@@ -2,7 +2,8 @@
 
   * ChaosSpec validation / JSON round trip / topology checks, and the
     full ``FAULTS`` registry surface ("flap", "join", "outage",
-    "random" — CI greps these literals).
+    "random" — ``tests/test_repo_hygiene.py`` looks for these
+    literals).
   * ``liveness_table`` semantics: flap toggles, join masks the prefix,
     outage darkens a region, down always wins; slice-stability
     (``liveness_table(spec, T)[a:b] == liveness_table(spec, b - a,
@@ -430,3 +431,24 @@ def test_acceptance_golden_bounds():
     assert f["recovery_windows"] <= 2.0
     assert c["gap_served_cells"] == c["down_site_windows"] == 320
     assert f["availability/region1"] == pytest.approx(1.0 - 20 / 48)
+
+
+def test_event_outage_and_join_end_to_end():
+    """Region 1 dark for windows 3-4 and site 0 joining at window 2, through
+    the event runtime under the rebalance controller: the down-cell count
+    is exact, answers stay finite and the budgets reconverge."""
+    E, W = 6, 64
+    scenario = ScenarioConfig(
+        data=DataSpec(dataset="fleet", n_points=8 * W, window=W, seed=5,
+                      options={"k": 4}),
+        budget_fraction=0.25, planner=PlannerConfig(solver="closed_form"),
+        topology=TopologySpec(n_regions=2, sites_per_region=E // 2, seed=5,
+                              latency_scale=0.0),
+        controller=ControllerSpec(mode="rebalance"), queries=("AVG", "VAR"),
+        chaos=ChaosSpec(outages=((3, 2, 1),), joins=((2, 0),)))
+    rep = Experiment.from_scenario(scenario).run()
+    # site 0 before its join (2 windows) + region 1's 3 sites for 2 windows
+    assert rep.down_site_windows == 2 + 2 * 3
+    assert np.isfinite(rep.nrmse["AVG"])
+    assert np.isfinite(rep.recovery_windows)
+    assert rep.raw["liveness"].shape == (8, E)

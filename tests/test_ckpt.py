@@ -7,14 +7,17 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.ckpt import CheckpointManager, latest_step, restore, save
-from repro.optim.adamw import adamw_init
 
 
 def _state(seed=0):
+    """A training-state-shaped pytree: nested float32 arrays, two float32
+    trees of zeros like them, and an int32 step counter."""
     k = jax.random.PRNGKey(seed)
     params = {"w": jax.random.normal(k, (8, 4)),
               "b": {"c": jnp.arange(5, dtype=jnp.float32)}}
-    return adamw_init(params)
+    zeros = jax.tree.map(jnp.zeros_like, params)
+    return {"params": params, "m": zeros, "v": zeros,
+            "step": jnp.asarray(0, jnp.int32)}
 
 
 def test_round_trip(tmp_path):

@@ -220,6 +220,25 @@ def test_fleet_experiment_with_faults():
     assert np.isfinite(r["fleet_nrmse"]["AVG"])
 
 
+def test_fleet_experiment_with_interpret_kernels():
+    """The whole fleet experiment (event loop, rebalancing, freshness) with
+    both Pallas kernels in interpret mode in place of the jnp reference."""
+    from repro.api import (ControllerSpec, DataSpec, Experiment,
+                           ScenarioConfig, TopologySpec)
+    E, R, K, W = 6, 2, 4, 128
+    scenario = ScenarioConfig(
+        data=DataSpec(dataset="fleet", n_points=2 * W, window=W, seed=0,
+                      options={"k": K}),
+        budget_fraction=0.25, planner=PlannerConfig(solver="closed_form"),
+        topology=TopologySpec(n_regions=R, sites_per_region=E // R, seed=0),
+        controller=ControllerSpec(), queries=("AVG", "VAR"))
+    res = Experiment.from_scenario(scenario, use_kernel=True,
+                                   interpret=True).run()
+    assert np.isfinite(res.nrmse["AVG"])
+    assert res.wan_bytes < res.full_bytes
+    assert np.isfinite(res.freshness_ms["p99_ms"])
+
+
 def test_fleet_kernel_path_interpret():
     """The Pallas block-diagonal kernel path, interpret mode (CI smoke)."""
     E, R, k, W = 4, 2, 4, 128

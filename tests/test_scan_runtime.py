@@ -636,13 +636,15 @@ def test_sharded_ckpt_interchange():
 def test_sharded_runtime_parity_under_forced_devices():
     """The tentpole pin: under 8 forced host devices the sharded runtime
     reproduces the batched scan's RunReport on the static, rebalance,
-    chaos and adaptive scenarios, and checkpoints interchange with the
-    batched runtime in both directions."""
+    chaos and adaptive scenarios, checkpoints interchange with the
+    batched runtime in both directions, and padding E=6 to 16 rows
+    instead of 8 changes nothing."""
     prog = textwrap.dedent("""
         import jax
         assert len(jax.devices()) == 8, jax.devices()
         import test_scan_runtime as t
         t._assert_sharded_runtime_all_parity()
+        t._assert_sharded_padding_invariant(1)
         print("OK", len(jax.devices()))
     """)
     out = subprocess.run([sys.executable, "-c", prog],
