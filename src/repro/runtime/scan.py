@@ -1,8 +1,9 @@
 """ScanRuntime — the on-device streaming engine.
 
 Where the event loop (``repro.api.experiment``) re-enters JAX once per
-window, this runtime stacks the window sequence into one device pool and
-runs the whole ingest → plan → sample → impute → serve cycle as a single
+window, this runtime puts each window of the sequence straight onto its
+device(s), stacks them there into one device pool and runs the whole
+ingest → plan → sample → impute → serve cycle as a single
 ``lax.scan`` over window ids with a donated :class:`RuntimeState` carry —
 E=256+ sites over thousands of windows execute as one XLA while-loop with
 no per-window host round-trips.
@@ -71,7 +72,8 @@ class _Prepared(NamedTuple):
     fn: Callable
     state: "RuntimeState"
     xs: object                         # wids, or (wids, live rows)
-    pool_np: np.ndarray
+    host: list                         # (run sites, k, N) f32 host windows
+    staged: int                        # bytes the host copied to make them
     live_tbl: Optional[np.ndarray]
     k: int
     n: int
@@ -152,6 +154,9 @@ class ScanRuntime:
         else:
             self._cost = np.ones(1)
         self._fns = {}                 # static_exec key -> jitted scan fn
+        # the placed windows -> the (P, E, k, N) pool, on the device(s)
+        # that hold them
+        self._stack = jax.jit(jnp.stack)
         # site rows the compiled step carries: == n_sites here; the sharded
         # runtime overrides it with E padded to the device multiple
         self._run_sites = self.n_sites
@@ -245,9 +250,10 @@ class ScanRuntime:
     # The sharded runtime (repro.runtime.sharded) reuses this run() driver
     # and specializes exactly four seams: how a resumed state enters the
     # run (padding), which liveness table the step consumes (padding
-    # columns as permanently-dead sites), how state, inputs and pool land
-    # on the device(s), and how results/state leave (slicing the padding
-    # back off).
+    # columns as permanently-dead sites), how state, inputs and windows
+    # land on the device(s), and how results/state leave (slicing the
+    # padding back off).  Windows are padded to the run's site rows in
+    # _stage, which both runtimes share.
 
     def _adopt_state(self, state):
         """A checkpointed RuntimeState entering this run's site layout."""
@@ -261,19 +267,34 @@ class ScanRuntime:
         return liveness_table(self.chaos, T, self.n_sites,
                               self.topology.region_of(), first_window=w0)
 
-    def _place(self, state, xs, pool_np):
-        """Host (state, xs, pool) -> the scan fn's device arguments."""
+    def _place(self, state, xs, host):
+        """Host (state, xs, windows) -> the scan fn's device arguments:
+        each window goes to the device on its own and the pool is stacked
+        there."""
         return (jax.tree.map(jnp.asarray, state),
-                jax.tree.map(jnp.asarray, xs), jnp.asarray(pool_np))
+                jax.tree.map(jnp.asarray, xs),
+                self._stack(jax.device_put(host)))
 
     def _finalize(self, ys, state, live_tbl):
         """Host-side (ys, final_state, live_tbl) right after the scan."""
         return ys, state, live_tbl
 
     # ----------------------------------------------------------------- run
+    def _stage(self, values) -> tuple:
+        """One window as the device takes it: a C-contiguous float32
+        (run sites, k, N) host array, ``values`` itself where it already is
+        one, and the bytes the host copied to make it (0 where none)."""
+        src = np.asarray(values)
+        w = np.ascontiguousarray(src, np.float32)
+        pad = self._run_sites - w.shape[0]
+        if pad:
+            w = np.concatenate([w, np.zeros((pad,) + w.shape[1:], w.dtype)])
+        return w, 0 if np.may_share_memory(w, src) else w.nbytes
+
     def _prepare(self, windows, n_windows, state, first_window):
-        """Stack the pool, build or adopt the carry and fetch the compiled
-        scan fn: what ``run`` and ``lower`` share before ``_place``."""
+        """Stage the windows, build or adopt the carry and fetch the
+        compiled scan fn: what ``run`` and ``lower`` share before
+        ``_place``."""
         single = self.topology is None
         if single:
             k = int(windows[0].k)
@@ -282,12 +303,13 @@ class ScanRuntime:
                 if not np.all(np.asarray(w.counts) == n):
                     raise ValueError("the scan runtime requires full "
                                      "windows (uniform counts)")
-            pool_np = np.stack([np.asarray(w.values, np.float32)
-                                for w in windows])[:, None]
+            staged = [self._stage(np.asarray(w.values)[None])
+                      for w in windows]
         else:
-            pool_np = np.stack([np.asarray(w, np.float32) for w in windows])
-            _, _, k, n = pool_np.shape
-        P = pool_np.shape[0]
+            staged = [self._stage(w) for w in windows]
+            _, k, n = staged[0][0].shape
+        host = [w for w, _ in staged]
+        P = len(host)
         T = int(n_windows) if n_windows is not None else P
 
         static_exec = self._static_exec(k, n)
@@ -323,8 +345,8 @@ class ScanRuntime:
         wids = np.arange(w0, w0 + T, dtype=np.int32)
         xs = wids if live_tbl is None else (wids, live_tbl)
         return _Prepared(fn=self._scan_fn(static_exec), state=state, xs=xs,
-                         pool_np=pool_np, live_tbl=live_tbl, k=k, n=n, T=T,
-                         w0=w0)
+                         host=host, staged=sum(b for _, b in staged),
+                         live_tbl=live_tbl, k=k, n=n, T=T, w0=w0)
 
     def lower(self, windows, n_windows: Optional[int] = None):
         """The ``jax.stages.Lowered`` scan program a fresh ``run`` over
@@ -332,7 +354,7 @@ class ScanRuntime:
         them.  ``.compile().as_text()`` shows what runs on the device —
         e.g. one ``tpu_custom_call`` per Pallas kernel on a TPU."""
         prep = self._prepare(windows, n_windows, None, None)
-        return prep.fn.lower(*self._place(prep.state, prep.xs, prep.pool_np))
+        return prep.fn.lower(*self._place(prep.state, prep.xs, prep.host))
 
     def run(self, windows, n_windows: Optional[int] = None, *,
             state=None, first_window: Optional[int] = None) -> dict:
@@ -357,9 +379,12 @@ class ScanRuntime:
         ``call`` (this runtime's count of ``run`` calls) and ``windows``
         (T); see docs/runtime.md, "Tracing a serving process".
         ``scan.place`` also carries ``devices`` (the devices the arguments
-        went to) and ``bytes`` (what they hold there, replicas counted on
-        every device).  The result's ``exchange_*`` counters are the window
-        step's cross-device collectives a window (0 on one device).
+        went to), ``bytes`` (what they hold there, replicas counted on
+        every device) and ``staged_bytes`` (what the host copied to cast,
+        make contiguous or pad the windows before they went; 0 when every
+        window went as the caller gave it).  The result's ``exchange_*``
+        counters are the window step's cross-device collectives a window
+        (0 on one device).
         """
         single = self.topology is None
         self._calls += 1
@@ -370,13 +395,14 @@ class ScanRuntime:
                                                 windows=T)
 
         with span("scan.prepare"):
-            fn, state, xs, pool_np, live_tbl, k, n, T, w0 = \
+            fn, state, xs, host, staged, live_tbl, k, n, T, w0 = \
                 self._prepare(windows, n_windows, state, first_window)
         with span("scan.place") as place:
-            state, xs, pool = self._place(state, xs, pool_np)
+            state, xs, pool = self._place(state, xs, host)
             if place.is_enabled():
                 place.set_metadata(devices=len(pool.sharding.device_set),
-                                   bytes=placed_bytes((state, xs, pool)))
+                                   bytes=placed_bytes((state, xs, pool)),
+                                   staged_bytes=staged)
 
         with span("scan.execute"):
             t0 = time.perf_counter()
@@ -399,7 +425,7 @@ class ScanRuntime:
             ys, state, live_tbl = self._finalize(ys, state, live_tbl)
             if self.collect == "payloads":
                 est, tru, bytes_site, cost_site = self._replay(
-                    ys, pool_np, T, windows, w0=w0, live_tbl=live_tbl)
+                    ys, host, T, windows, w0=w0, live_tbl=live_tbl)
             else:
                 est = {q: np.asarray(ys["est"][q], np.float64)
                        for q in self.query_names}
@@ -434,10 +460,11 @@ class ScanRuntime:
                                       live_tbl=live_tbl)
 
     # ------------------------------------------------------------- results
-    def _replay(self, ys, pool_np, T, windows, w0: int = 0, live_tbl=None):
+    def _replay(self, ys, host, T, windows, w0: int = 0, live_tbl=None):
         """Host replay of the collected payloads through the event path's
         own assemble/reconstruct/query code — the bitwise report mode.
 
+        ``host`` holds the staged windows the pool was stacked from.
         ``w0`` is the first window id of a resumed run: output row ``t``
         holds window ``w0 + t``, which read pool slot ``(w0 + t) % P``.
 
@@ -448,8 +475,8 @@ class ScanRuntime:
         """
         from repro.core.reconstruct import reconstruct_window
         from repro.planning.engine import assemble_payload
-        E, k = self.n_sites, pool_np.shape[2]
-        P = pool_np.shape[0]
+        E, k = self.n_sites, host[0].shape[1]
+        P = len(host)
         qnames = self.query_names
         est = {q: np.full((T, E, k), np.nan) for q in qnames}
         tru = {q: np.full((T, E, k), np.nan) for q in qnames}
@@ -459,7 +486,7 @@ class ScanRuntime:
         last_rec = [None] * E          # gap-serving memory (chaos only)
         for t in range(T):
             plan_t = {f: ys[f][t] for f in PAYLOAD_PLAN_FIELDS}
-            vals = pool_np[(w0 + t) % P]
+            vals = host[(w0 + t) % P]
             for s in range(E):
                 if live_tbl is not None and not live_tbl[t, s]:
                     vals_true = [vals[s, i] for i in range(k)]

@@ -18,9 +18,12 @@ Mesh layout / padding
     liveness mask chaos faults use
     (:func:`~repro.chaos.padded_liveness_table`), so the step always runs
     its ``chaos=True`` body and every dead-site guarantee (zero budget,
-    zero bytes, frozen EWMAs, no ingest) covers padding for free.  Pool,
+    zero bytes, frozen EWMAs, no ingest) covers padding for free.  Windows,
     carry and liveness go from the host straight to their devices
-    (``jax.device_put`` on ``NamedSharding``s), never staged on one chip.
+    (``jax.device_put`` on ``NamedSharding``s), never staged on one chip:
+    each window is split on its site rows, which are contiguous in the
+    caller's array, and every chip stacks its own rows into its shard of
+    the pool.
 
 Collective inventory (per window, rebalance controller only)
     ``water_fill`` — 1 + 2·iters ``all_gather`` of the (E,) vectors it
@@ -102,6 +105,8 @@ class ShardedScanRuntime(ScanRuntime):
                 f"pad_sites ({e_pad}) must be >= n_sites ({e}) and a "
                 f"multiple of the {d}-device site mesh")
         self._run_sites = e_pad
+        self._stack = jax.jit(
+            jnp.stack, out_shardings=NamedSharding(self._mesh, P(None, AXIS)))
 
     # ------------------------------------------------------------- compile
     def _plan_fn(self, values, counts, budgets):
@@ -200,24 +205,18 @@ class ShardedScanRuntime(ScanRuntime):
                                      self.topology.region_of(),
                                      first_window=w0)
 
-    def _place(self, state, xs, pool_np):
+    def _place(self, state, xs, host):
         """Each device receives only its own site shard, straight from the
-        host: pool and liveness split on their site axis, the carry by
-        :meth:`_state_specs`, window ids replicated."""
-        pad = self._run_sites - self.n_sites
-        if pad:
-            pool_np = np.concatenate(
-                [pool_np, np.zeros((pool_np.shape[0], pad)
-                                   + pool_np.shape[2:], pool_np.dtype)],
-                axis=1)
-
+        host: every window and the liveness split on their site axis, the
+        carry by :meth:`_state_specs`, window ids replicated; each device
+        then stacks its window shards into its shard of the pool."""
         def put(x, spec):
             return jax.device_put(x, NamedSharding(self._mesh, spec))
 
         state = jax.tree.map(put, state, self._state_specs(state))
         wids, live = xs
         xs = (put(wids, P()), put(live, P(None, AXIS)))
-        return state, xs, put(pool_np, P(None, AXIS))
+        return state, xs, self._stack(put(host, P(AXIS)))
 
     def _finalize(self, ys, state, live_tbl):
         """Slice padding off every output; hand back a state a *batched*

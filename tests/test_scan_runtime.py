@@ -78,9 +78,9 @@ def _scan_run_with_plans(scenario, windows):
     stash = {}
     replay = exp.runtime._replay
 
-    def spy(ys, pool_np, T, wins, w0=0, live_tbl=None):
+    def spy(ys, host, T, wins, w0=0, live_tbl=None):
         stash["ys"] = ys
-        return replay(ys, pool_np, T, wins, w0=w0, live_tbl=live_tbl)
+        return replay(ys, host, T, wins, w0=w0, live_tbl=live_tbl)
 
     exp.runtime._replay = spy
     return exp.run(windows), stash["ys"]
